@@ -9,15 +9,16 @@ The fused-Adam traffic model is the DESIGN.md §3 argument in numbers:
 Backend axis (DESIGN.md §10): ``--backend <name|all>`` times the
 sparse-rows CS-Adam step through each registered kernel backend
 (ref | stream | tiled | interpret) on a duplicate-heavy id batch, so the
-stream-vs-tiled crossover is *measured*, not asserted.  Off-TPU the
-Pallas backends run in interpret mode — their absolute numbers are
+stream-vs-tiled crossover is *measured*, not asserted.  'stream' and
+'tiled' compile for a TPU only; off-TPU ``all`` times 'interpret' (the
+tiled kernel under the interpreter) instead — its absolute numbers are
 Python-interpreter timings, only the grid-step counts (k for stream,
 k/TILE for tiled) transfer to hardware; the traffic model supplies the
 projected ratio.
 
     PYTHONPATH=src python benchmarks/kernels.py                 # ref only
     PYTHONPATH=src python benchmarks/kernels.py --backend all
-    PYTHONPATH=src python benchmarks/kernels.py --backend tiled
+    PYTHONPATH=src python benchmarks/kernels.py --backend tiled  # TPU
 """
 from __future__ import annotations
 
@@ -130,7 +131,11 @@ def run(quick: bool = False, backend: Optional[str] = None):
     if backend is None:
         names = ["ref"]               # default: the fast-on-CPU oracle only
     elif backend == "all":
-        names = list(K.backends())
+        # 'stream' and 'tiled' compile for a TPU only; elsewhere the
+        # kernel body runs by name under the interpreter ('interpret')
+        names = [n for n in K.backends()
+                 if jax.default_backend() == "tpu"
+                 or n not in ("stream", "tiled")]
     else:
         names = [K.resolve_backend(backend)]
     # interpret-mode Pallas on CPU is slow — shrink the batch there

@@ -30,6 +30,7 @@ traffic counterpart rows live in benchmarks/traffic.py.
 import argparse
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 
@@ -83,7 +84,7 @@ def run(n_rows: int, dim: int, batch: int, steps: int, budget: int,
 
     # 3. train the sparse-embedding regression on the 8-way 'model' mesh
     tree = plan.store_tree()
-    mesh = shd.make_mesh_compat((shards,), ("model",))
+    mesh = jax.make_mesh((shards,), ("model",), axis_types=(AxisType.Auto,))
     init_fn, step_fn, opt = make_sparse_embedding_step(
         n_rows, dim, lr=lr, stores=tree, path=PATH, mesh=mesh,
         sketch_shards=shards, shard_layout=layout)

@@ -34,6 +34,7 @@ EXPERIMENTS.md §Traffic is generated from them.
 import argparse
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 
 try:
@@ -79,7 +80,7 @@ def dense_dp_step(mesh, n_rows, dim, hp):
 
 
 def run(n_rows: int, dim: int, batch: int, compressions) -> dict:
-    mesh = shd.make_mesh_compat((N_DEV,), ("data",))
+    mesh = jax.make_mesh((N_DEV,), ("data",), axis_types=(AxisType.Auto,))
     rows_arr = jnp.zeros((batch, dim), jnp.float32)
     ids_arr = jnp.zeros((batch,), jnp.int32)
     table = jnp.zeros((n_rows, dim), jnp.float32)
@@ -100,15 +101,16 @@ def run(n_rows: int, dim: int, batch: int, compressions) -> dict:
         # sharded-sketch routing row (DESIGN.md §17): shard-only mesh —
         # no dp axis, so the shard-axis routing psum is the step's ONLY
         # collective and the measured HLO bytes are pure routing traffic
-        mesh_sh = shd.make_mesh_compat((N_DEV,), ("model",))
+        mesh_sh = jax.make_mesh((N_DEV,), ("model",),
+                                axis_types=(AxisType.Auto,))
         _, sh_step, sh_opt = make_sparse_embedding_step(
             n_rows, dim, lr=1e-2, hparams=hp, mesh=mesh_sh,
             sketch_shards=N_DEV)
         rt_cols = _collective_bytes(
             sh_step, (table, sh_opt.init(), ids_arr, rows_arr))
         # composed dp × shard: the PR 4 psum payload shrinks to slabs
-        mesh_2d = shd.make_mesh_compat((N_DEV // SHARDS, SHARDS),
-                                       ("data", "model"))
+        mesh_2d = jax.make_mesh((N_DEV // SHARDS, SHARDS), ("data", "model"),
+                                axis_types=(AxisType.Auto,) * 2)
         _, ds_step, ds_opt = make_sparse_embedding_step(
             n_rows, dim, lr=1e-2, hparams=hp, dp_axis="data", mesh=mesh_2d,
             sketch_shards=SHARDS)

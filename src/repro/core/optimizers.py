@@ -434,7 +434,8 @@ def sparse_rows_adam(lr: Schedule, b1: float = 0.9, b2: float = 0.999,
                      track_first_moment: bool = True,
                      cleaning: Optional[CleaningSchedule] = None,
                      m_store: Optional[AuxStore] = None,
-                     v_store: Optional[AuxStore] = None) -> Transform:
+                     v_store: Optional[AuxStore] = None,
+                     dir_clip: Optional[float] = None) -> Transform:
     """Optax-shaped CS-Adam for ONE (n, d) table fed (ids, rows) gradients
     — ``chain(scale_by_adam_rows(m_store=..., v_store=...),
     scale_by_lr(lr))`` in the legacy state layout.
@@ -450,7 +451,8 @@ def sparse_rows_adam(lr: Schedule, b1: float = 0.9, b2: float = 0.999,
     bound ``CountSketchStore``/``CountMinStore``, e.g. from a planner
     ``StoreTree``).  ``track_first_moment=False`` is the β₁=0 (Theorem
     5.1 / RMSProp) variant the paper uses for the 49.5M-class Amazon
-    task."""
+    task.  ``dir_clip``: per-coordinate direction trust clamp
+    (``transforms.scale_by_adam_rows``); None leaves it unclamped."""
     if hparams.strict_paper:
         raise ValueError("sparse_rows_adam always runs through the kernel "
                          "registry, which has no strict_paper (3-pass) "
@@ -464,7 +466,8 @@ def sparse_rows_adam(lr: Schedule, b1: float = 0.9, b2: float = 0.999,
     backend = getattr(v_store, "backend", None) or hparams.backend
     rule = T.scale_by_adam_rows(
         b1=b1, b2=b2, eps=eps, m_store=m_store, v_store=v_store,
-        backend=backend if backend is not None else "auto")
+        backend=backend if backend is not None else "auto",
+        dir_clip=dir_clip)
     return _with_lr(rule, lr)
 
 

@@ -221,18 +221,30 @@ def sr_seed_or_default(spec: SketchSpec, sr_seed):
 
 
 def median_rows(rows) -> jnp.ndarray:
-    """Median over a LIST of per-depth rows.  depth==3 avoids a sort
-    (a+b+c−max−min, pairwise extrema) — the single source of the
-    estimator identity shared by the reference query, the fused XLA
-    update_read, and the Pallas kernels (bit-identity across them
-    depends on these exact forms)."""
-    if len(rows) == 1:
+    """Median over a LIST of per-depth rows, elementwise min/max only —
+    the single source of the estimator identity shared by the reference
+    query, the fused XLA update_read, and the Pallas kernels (bit-identity
+    across them depends on these exact forms; a sort does not lower in a
+    TPU kernel).
+
+    depth 3 keeps its historical form a+b+c−max−min; other depths run an
+    odd-even transposition network and take the middle element (odd) or
+    ``jnp.median``'s midpoint ``(lo + hi) · 0.5`` (even)."""
+    n = len(rows)
+    if n == 1:
         return rows[0]
-    if len(rows) == 3:
+    if n == 3:
         hi = jnp.maximum(jnp.maximum(rows[0], rows[1]), rows[2])
         lo = jnp.minimum(jnp.minimum(rows[0], rows[1]), rows[2])
         return rows[0] + rows[1] + rows[2] - hi - lo
-    return jnp.median(jnp.stack(rows), axis=0)
+    vals = list(rows)
+    for rnd in range(n):
+        for i in range(rnd % 2, n - 1, 2):
+            vals[i], vals[i + 1] = (jnp.minimum(vals[i], vals[i + 1]),
+                                    jnp.maximum(vals[i], vals[i + 1]))
+    if n % 2:
+        return vals[n // 2]
+    return (vals[n // 2 - 1] + vals[n // 2]) * 0.5
 
 
 def _median_depth(vals: jnp.ndarray) -> jnp.ndarray:

@@ -686,7 +686,8 @@ def scale_by_adam_rows(b1: float = 0.9, b2: float = 0.999,
                        eps: float = 1e-8, *,
                        m_store: Optional[AuxStore],
                        v_store: AuxStore,
-                       backend: Optional[str] = None) -> Transform:
+                       backend: Optional[str] = None,
+                       dir_clip: Optional[float] = None) -> Transform:
     """``scale_by_adam`` for ONE table fed ``{"ids": (k,), "rows": (k, d)}``
     gradients — the sampled-softmax / extreme-classification regime where
     work scales with touched rows.
@@ -698,7 +699,14 @@ def scale_by_adam_rows(b1: float = 0.9, b2: float = 0.999,
     'interpret', None/'auto' = per-host best), which handles duplicate
     ids.  Emits ``{"ids", "rows": direction}`` with the direction
     *unscaled* — compose with ``scale_by_lr`` (which leaves the integer
-    ``ids`` leaf untouched) and apply via ``apply_sparse_updates``."""
+    ``ids`` leaf untouched) and apply via ``apply_sparse_updates``.
+
+    ``dir_clip``: the per-coordinate trust clamp of the DP path
+    (``sketched_reduce.dp_adam_rows``), for the same reason: the numerator
+    m̂ is a signed-median sketch estimate, so a row whose buckets hold a
+    heavier row's first moment, divided by its own small count-min
+    second moment, can get a direction of 1e5 and more where exact Adam
+    stays within a few units.  None (the default) leaves it unclamped."""
     for name, store, kinds in (("m_store", m_store, ("sketch",)),
                                ("v_store", v_store, ("countmin", "sketch"))):
         if store is None:
@@ -724,6 +732,8 @@ def scale_by_adam_rows(b1: float = 0.9, b2: float = 0.999,
         M, V, direction = kernels.adam_rows(
             spec_m, spec_v, state["m"], V_in, ids, rows, step,
             lr=-1.0, b1=b1, b2=b2, eps=eps, backend=backend)
+        if dir_clip is not None:
+            direction = jnp.clip(direction, -dir_clip, dir_clip)
         return ({"ids": ids, "rows": direction},
                 {"step": step, "m": M, "v": V})
 

@@ -30,36 +30,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # ---------------------------------------------------------------------------
-# JAX version compat
-# ---------------------------------------------------------------------------
-
-def make_mesh_compat(axis_shapes: Sequence[int],
-                     axis_names: Sequence[str]) -> Mesh:
-    """``jax.make_mesh`` that works across JAX versions.
-
-    Newer JAX (>= 0.5) grew ``jax.sharding.AxisType`` and defaults new
-    meshes to *explicit* axis types, which breaks code written for the
-    classic auto-sharding GSPMD mode; older JAX (this container's 0.4.x)
-    has no ``AxisType`` at all.  Always request Auto axes when the knob
-    exists and omit it when it doesn't.
-    """
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = {}
-    if axis_type is not None:
-        kwargs["axis_types"] = (axis_type.Auto,) * len(tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
-
-
-def shard_map_compat(*args, **kwargs):
-    """``jax.shard_map`` (JAX >= 0.5) / ``jax.experimental.shard_map``
-    (0.4.x) under one name."""
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-    return fn(*args, **kwargs)
-
-
-# ---------------------------------------------------------------------------
 # Rule table: (path regex, per-dim axis template)
 # Templates name mesh axes; 'fsdp:<axis>' entries apply only when the
 # config opts into fsdp.  Matched against the path *suffix*.
@@ -473,10 +443,9 @@ def dp_sparse_wrap(local_fn, *, mesh: Optional[Mesh] = None,
                 f"dp sparse steps over {dp_axis!r} need a mesh: pass "
                 f"mesh= or trace inside shd.active_mesh(mesh)")
         dp = P(dp_axis)
-        return shard_map_unchecked(
-            local_fn, mesh=use_mesh,
-            in_specs=(P(), P(), dp, dp),
-            out_specs=(P(), P()))(table, state, ids, rows)
+        return jax.shard_map(
+            local_fn, mesh=use_mesh, in_specs=(P(), P(), dp, dp),
+            out_specs=(P(), P()), check_vma=False)(table, state, ids, rows)
 
     return wrapped
 
@@ -512,7 +481,7 @@ def sharded_sparse_wrap(local_fn, *, mesh: Optional[Mesh] = None,
     The body must be written in slab terms (``sharded_adam_rows``); its
     table/direction outputs are replicated by construction (psum- and
     all_gather-derived), which the static checker can't prove — hence
-    ``shard_map_unchecked``."""
+    ``check_vma=False``."""
 
     def wrapped(table, state, ids, rows):
         use_mesh = mesh if mesh is not None else current_mesh()
@@ -522,26 +491,11 @@ def sharded_sparse_wrap(local_fn, *, mesh: Optional[Mesh] = None,
                 f"pass mesh= or trace inside shd.active_mesh(mesh)")
         dp = P(dp_axis) if dp_axis is not None else P()
         sspecs = sketch_state_specs(state, shard_axis)
-        return shard_map_unchecked(
-            local_fn, mesh=use_mesh,
-            in_specs=(P(), sspecs, dp, dp),
-            out_specs=(P(), sspecs))(table, state, ids, rows)
+        return jax.shard_map(
+            local_fn, mesh=use_mesh, in_specs=(P(), sspecs, dp, dp),
+            out_specs=(P(), sspecs), check_vma=False)(table, state, ids, rows)
 
     return wrapped
-
-
-def shard_map_unchecked(f, *, mesh, in_specs, out_specs):
-    """``shard_map`` with replication checking off, across the JAX
-    versions that spell the knob ``check_rep`` (≤ 0.4.x) or ``check_vma``
-    (newer): the DP step's outputs are replicated by construction (psum /
-    all_gather derived), which the static checker cannot always prove."""
-    for kw in ({"check_rep": False}, {"check_vma": False}, {}):
-        try:
-            return shard_map_compat(f, mesh=mesh, in_specs=in_specs,
-                                    out_specs=out_specs, **kw)
-        except TypeError:
-            continue
-    raise AssertionError("unreachable: bare shard_map rejected")
 
 
 def constraint(x, spec: P):

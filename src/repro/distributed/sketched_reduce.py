@@ -249,9 +249,9 @@ def dp_adam_rows(spec_m: Optional[cs.SketchSpec], spec_v: cs.SketchSpec,
     'drop' ignores).
 
     ``dir_clip``: per-coordinate trust clamp on the emitted direction.
-    Unlike the single-device kernels (whose numerator is the EXACT
-    gradient row), both moments here are sketch queries — a signed-median
-    numerator over a count-min denominator — so per-id estimator mismatch
+    Both moments here are sketch queries — a signed-median numerator over
+    a count-min denominator (as on one device:
+    ``transforms.scale_by_adam_rows``) — so per-id estimator mismatch
     can exceed exact Adam's ~1-bounded |m̂/√v̂| ratio and, fed back
     through the loss, diverge.  Exact Adam never legitimately exceeds a
     few units per coordinate; the clamp (default 10) only ever removes

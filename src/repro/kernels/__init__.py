@@ -11,7 +11,10 @@
                      op (DESIGN.md §14)
   dedup.py         — sort + segment-sum pre-pass that turns an (ids, rows)
                      batch collision-free so the tiled kernel applies
-  ops.py           — jit'd wrappers w/ TPU→Pallas, CPU→ref dispatch
+  row_groups.py    — the aligned row-group DMA + one-hot select/place
+                     machinery both tiled kernels share, and the rules
+                     for what the TPU compiler accepts
+  ops.py           — jit'd wrappers, one implementation per backend name
   ref.py           — pure-jnp oracles (bit-exact semantics definitions)
   registry.py      — the shared (store kind, op) → {backend: fn} registry
 
@@ -33,9 +36,10 @@ manifests), ``launch/train.py --store-backend``, and the benchmarks.
              step; exact per-item semantics, throughput-bound
   tiled      dedup pre-pass + ``cs_adam_tiled`` — TILE rows per grid step;
              identical to ``ref`` on collision-free batches, within
-             median/min-noise tolerance otherwise (the TPU fast path)
-  interpret  ``tiled`` with the Pallas interpreter forced on — runs the
-             kernel body anywhere (tests, CPU containers)
+             median/min-noise tolerance otherwise (the TPU fast path:
+             f32 cells, dim a multiple of 128)
+  interpret  ``tiled`` under the Pallas interpreter — runs the kernel
+             body on any host; chosen only by name (tests)
 
 ('sketch' | 'countmin', 'update_read') — the dense-path fused one-pass
 EMA op of the ``AuxStore`` protocol (DESIGN.md §14):
@@ -45,8 +49,9 @@ EMA op of the ``AuxStore`` protocol (DESIGN.md §14):
   xla        one fused gather/Δ/scatter pass, addressing hashed once (and
              host-cached for the dense arange(n) row set) — bit-identical
              to ``ref``
-  tiled      the ``cs_ema_tiled`` Pallas kernel (TPU fast path)
-  interpret  ``tiled`` under the Pallas interpreter
+  tiled      the ``cs_ema_tiled`` Pallas kernel (TPU fast path: f32 or
+             bf16 cells, dim a multiple of 128)
+  interpret  ``tiled`` under the Pallas interpreter (by name only)
 
 ('sketch' | 'countmin', 'update_slab' | 'gather_slab') — the shard-local
 halves of the sharded optimizer body (DESIGN.md §17): masked scatter-add
@@ -60,15 +65,21 @@ into / gather out of one shard's (depth, local_width, dim) slab.
 
 'stream' exists only for the pair op (per-item ordering is its point);
 ``update_read`` is defined batch-wise.  ``resolve_backend(None|'auto')``
-picks ``tiled`` on TPU and ``xla`` elsewhere.  New backends (e.g. a GPU
-port) attach via ``registry.register``.
+picks ``tiled`` on TPU and ``xla`` elsewhere; given the op's sketch specs
+(as ``adam_rows``/``update_read`` do) it picks ``xla`` for a sketch the
+kernel refuses (int8 cells, dim not a multiple of 128, bf16 in the pair
+op), and an explicit backend name that refuses a sketch raises.  New
+backends (e.g. a GPU port) attach via ``registry.register``.
 """
 from __future__ import annotations
 
 import functools
 from typing import Callable, Optional, Tuple
 
+import jax.numpy as jnp
+
 from repro.kernels import dedup, ops, ref, registry  # noqa: F401
+from repro.kernels import row_groups as rg
 
 
 def register_backend(name: str, fn: Callable) -> None:
@@ -101,7 +112,9 @@ def adam_rows(spec_m, spec_v, M, V, ids, g, step, *,
     correct application under every backend (the tiled backend zeros
     duplicate occurrences after the first; see ``dedup.scatter_back``).
     """
-    fn = registry.lookup("pair", "adam_rows", backend)
+    name = registry.resolve("pair", "adam_rows", backend,
+                            specs=(spec_m, spec_v))
+    fn = registry.lookup("pair", "adam_rows", name)
     return fn(spec_m, spec_v, M, V, ids, g, step,
               lr=lr, b1=b1, b2=b2, eps=eps)
 
@@ -121,7 +134,8 @@ def update_read(spec, S, ids, delta, *, beta: float, scale: float,
     training loop MUST thread the step so successive writes draw fresh
     rounding bits (DESIGN.md §18)."""
     kind = "sketch" if spec.signed else "countmin"
-    fn = registry.lookup(kind, "update_read", backend)
+    name = registry.resolve(kind, "update_read", backend, specs=(spec,))
+    fn = registry.lookup(kind, "update_read", name)
     return fn(spec, S, ids, delta, beta=beta, scale=scale, mask=mask,
               sr_seed=sr_seed)
 
@@ -138,7 +152,8 @@ def update_slab(spec, slab, ids, delta, shard, *,
     if backend in (None, "auto") \
             or backend not in registry.backends(kind, "update_slab"):
         backend = "xla"
-    fn = registry.lookup(kind, "update_slab", backend)
+    name = registry.resolve(kind, "update_slab", backend, specs=(spec,))
+    fn = registry.lookup(kind, "update_slab", name)
     return fn(spec, slab, ids, delta, shard)
 
 
@@ -151,25 +166,54 @@ def gather_slab(spec, slab, ids, shard, *, backend: Optional[str] = None):
     if backend in (None, "auto") \
             or backend not in registry.backends(kind, "gather_slab"):
         backend = "xla"
-    fn = registry.lookup(kind, "gather_slab", backend)
+    name = registry.resolve(kind, "gather_slab", backend, specs=(spec,))
+    fn = registry.lookup(kind, "gather_slab", name)
     return fn(spec, slab, ids, shard)
+
+
+def _f32_only(spec) -> Optional[str]:
+    if jnp.dtype(spec.dtype) != jnp.float32:
+        return (f"{jnp.dtype(spec.dtype).name} cells "
+                "(this kernel keeps float32)")
+    return None
+
+
+def _pair_kernel(spec) -> Optional[str]:
+    return _f32_only(spec) or rg.kernel_refusal(spec.width, spec.dtype)
+
+
+def _pair_tiled(spec) -> Optional[str]:
+    return _f32_only(spec) or rg.tiled_refusal(spec.dim, spec.width,
+                                               spec.dtype)
+
+
+def _ema_kernel(spec) -> Optional[str]:
+    return rg.kernel_refusal(spec.width, spec.dtype)
+
+
+def _ema_tiled(spec) -> Optional[str]:
+    return rg.tiled_refusal(spec.dim, spec.width, spec.dtype)
 
 
 register_backend("ref", ops.adam_rows_ref)
 register_backend("xla", ops.adam_rows_xla)
-register_backend("stream", ops.adam_rows_stream)
-register_backend("tiled", ops.adam_rows_tiled)
-register_backend("interpret",
-                 functools.partial(ops.adam_rows_tiled, interpret=True))
+registry.register("pair", "adam_rows", "stream", ops.adam_rows_stream,
+                  refusal=_f32_only)
+registry.register("pair", "adam_rows", "tiled", ops.adam_rows_tiled,
+                  refusal=_pair_tiled)
+registry.register("pair", "adam_rows", "interpret",
+                  functools.partial(ops.adam_rows_tiled, interpret=True),
+                  refusal=_pair_kernel)
 
 for _kind in ("sketch", "countmin"):
     registry.register(_kind, "update_read", "ref", ops.ema_update_read_ref)
     registry.register(_kind, "update_read", "xla", ops.ema_update_read_xla)
     registry.register(_kind, "update_read", "tiled",
-                      ops.ema_update_read_tiled)
+                      ops.ema_update_read_tiled, refusal=_ema_tiled)
     registry.register(_kind, "update_read", "interpret",
                       functools.partial(ops.ema_update_read_tiled,
-                                        interpret=True))
+                                        interpret=True),
+                      refusal=_ema_kernel)
     registry.register(_kind, "update_slab", "ref", ops.cs.update_slab)
     registry.register(_kind, "update_slab", "xla", ops.slab_update_xla)
     registry.register(_kind, "gather_slab", "ref", ops.cs.gather_slab)

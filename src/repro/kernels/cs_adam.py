@@ -33,11 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-
-def _median3(a, b, c):
-    hi = jnp.maximum(jnp.maximum(a, b), c)
-    lo = jnp.minimum(jnp.minimum(a, b), c)
-    return a + b + c - hi - lo
+from repro.core.sketch import median_rows
 
 
 def _adam_kernel(depth: int, track_m: bool,
@@ -67,13 +63,8 @@ def _adam_kernel(depth: int, track_m: bool,
 
     # ---- 1st moment (count-sketch, signed median) ----------------------
     if track_m:
-        rows = [m_scr[j, 0, :] * sm_ref[j, i] for j in range(depth)]
-        if depth == 3:
-            m_old = _median3(*rows)
-        elif depth == 1:
-            m_old = rows[0]
-        else:
-            m_old = jnp.median(jnp.stack(rows), axis=0)
+        m_old = median_rows([m_scr[j, 0, :] * sm_ref[j, i]
+                             for j in range(depth)])
         dm = (1.0 - b1) * (g - m_old)
         for j in range(depth):
             m_scr[j, 0, :] = m_scr[j, 0, :] + sm_ref[j, i] * dm

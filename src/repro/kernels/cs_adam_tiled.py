@@ -12,24 +12,26 @@ ceiling:
     normal double-buffered BlockSpec pipeline, ``TILE`` rows per step —
     the compiler overlaps the step ``t+1`` fetch with step ``t`` compute;
   * the sketches stay in ``pl.ANY`` (HBM) and each step issues all
-    ``depth × TILE`` row DMAs at once (overlapped, one wait), instead of
-    the streaming kernel's per-item round trip;
+    ``depth × TILE`` DMAs of the aligned row groups that hold the
+    addressed buckets at once (overlapped, one wait; ``row_groups.py``),
+    instead of the streaming kernel's per-item round trip;
   * the row update itself is vectorized over the (TILE, d) block on the
     VPU, with the depth-way median/min unchanged.
 
 Bucket collisions *within* a tile (two unique ids hashing to the same
 bucket of hash row ``j``) still need scatter-ADD semantics, which the
 write-back DMAs alone cannot provide.  The kernel folds an intra-tile
-segment-sum into a (TILE, TILE) equality-matrix matmul:
+segment-sum into the group placement matmul of ``row_groups.Groups``:
 
-    eq_j[r, r']  = 1 if bucket_j[r] == bucket_j[r']
-    write_j      = gathered_j + eq_j @ contribution_j
+    write_j = gathered_groups_j + place_j @ contribution_j
 
-Duplicate-bucket rows then write back *identical* fully-accumulated
-values, so any DMA completion order is correct.  Estimates still read the
+Entries whose buckets share a row group then write back *identical*
+fully-accumulated groups, so any DMA completion order is correct.  Estimates still read the
 pre-tile sketch — batch semantics inside a tile, streaming semantics
 across tiles (tile t+1 observes tile t's writes through the sequential
-TPU grid; see cs_update.py for the same race-freedom argument).
+TPU grid; see cs_update.py for the same race-freedom argument), and
+batches longer than one call's SMEM address budget run as consecutive
+calls with the same streaming order.
 
 Rows past ``n_valid`` (dedup/tile padding) contribute exactly zero to
 every sketch bucket and emit zero update rows.
@@ -47,31 +49,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.core.sketch import median_rows
+from repro.kernels import row_groups as rg
+
 DEFAULT_TILE = 8
-
-
-def _median3(a, b, c):
-    hi = jnp.maximum(jnp.maximum(a, b), c)
-    lo = jnp.minimum(jnp.minimum(a, b), c)
-    return a + b + c - hi - lo
-
-
-def _median(rows):
-    if len(rows) == 1:
-        return rows[0]
-    if len(rows) == 3:
-        return _median3(*rows)
-    return jnp.median(jnp.stack(rows), axis=0)
-
-
-def _tile_vec(ref, j, base, tile):
-    """(tile,) vector of scalar-prefetch entries ref[j, base:base+tile]."""
-    return jnp.stack([ref[j, base + r] for r in range(tile)])
-
-
-def _eq_matrix(bkt):
-    """(tile, tile) float32 bucket-equality matrix for one hash row."""
-    return (bkt[:, None] == bkt[None, :]).astype(jnp.float32)
 
 
 def _tiled_kernel(depth: int, tile: int, track_m: bool,
@@ -79,75 +60,109 @@ def _tiled_kernel(depth: int, tile: int, track_m: bool,
                   hyper, g_blk,                     # SMEM hypers, VMEM grads
                   M_any, V_any,                     # sketches, pl.ANY (HBM)
                   M_out, V_out, upd_out,            # aliased outs + updates
-                  m_scr, v_scr, sem):               # scratch VMEM + DMA sem
+                  m_stage, v_stage, sem):           # group VMEM + DMA sem
     t = pl.program_id(0)
     base = t * tile
+    g = rg.group_rows(jnp.float32)
     lr, b1, b2, eps, bc1, bc2 = (hyper[0], hyper[1], hyper[2], hyper[3],
                                  hyper[4], hyper[5])
 
-    # ---- DMA in all depth×tile sketch rows, one overlapped burst ---------
+    def groups(ref):
+        return [rg.Groups([ref[j, base + r] for r in range(tile)], tile, g)
+                for j in range(depth)]
+
+    gm = groups(bm_ref) if track_m else []
+    gv = groups(bv_ref)
+
+    # ---- DMA in every addressed row group, one overlapped burst ---------
     copies = []
-    if track_m:
-        for j in range(depth):
-            for r in range(tile):
-                copies.append(pltpu.async_copy(
-                    M_out.at[j, pl.ds(bm_ref[j, base + r], 1), :],
-                    m_scr.at[j, pl.ds(r, 1)], sem))
     for j in range(depth):
-        for r in range(tile):
-            copies.append(pltpu.async_copy(
-                V_out.at[j, pl.ds(bv_ref[j, base + r], 1), :],
-                v_scr.at[j, pl.ds(r, 1)], sem))
+        if track_m:
+            copies += rg.dma_groups(M_out, m_stage, j, gm[j], sem,
+                                    to_hbm=False)
+        copies += rg.dma_groups(V_out, v_stage, j, gv[j], sem, to_hbm=False)
     for c in copies:
         c.wait()
 
-    g = g_blk[:, :]                                         # (tile, d)
+    g_rows = g_blk[:, :]                                    # (tile, d)
     row_pos = base + jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0)
     valid = (row_pos < nv_ref[0]).astype(jnp.float32)       # (tile, 1)
 
     # ---- 1st moment: median estimate, batched over the tile ---------------
     if track_m:
-        sgn = [_tile_vec(sm_ref, j, base, tile) for j in range(depth)]
-        eq_m = [_eq_matrix(_tile_vec(bm_ref, j, base, tile))
-                for j in range(depth)]
-        rows = [m_scr[j] * sgn[j][:, None] for j in range(depth)]
-        m_old = _median(rows)
-        dm = (1.0 - b1) * (g - m_old) * valid
+        sgn = [rg.row_vec([sm_ref[j, base + r] for r in range(tile)], tile,
+                          dtype=jnp.float32) for j in range(depth)]
+        blocks = [m_stage[j] for j in range(depth)]
+        m_old = median_rows([gm[j].read(blocks[j]) * sgn[j]
+                             for j in range(depth)])
+        dm = (1.0 - b1) * (g_rows - m_old) * valid
         for j in range(depth):
-            contrib = sgn[j][:, None] * dm                  # (tile, d)
-            m_scr[j] = m_scr[j] + jax.lax.dot(
-                eq_m[j], contrib, preferred_element_type=jnp.float32)
+            m_stage[j] = gm[j].add(blocks[j], sgn[j] * dm)
         mhat = (m_old + dm) / bc1
     else:
-        mhat = g
+        mhat = g_rows
 
     # ---- 2nd moment: min estimate (count-min) ------------------------------
-    eq_v = [_eq_matrix(_tile_vec(bv_ref, j, base, tile)) for j in range(depth)]
-    v_old = functools.reduce(jnp.minimum, [v_scr[j] for j in range(depth)])
-    dv = (1.0 - b2) * (g * g - v_old) * valid
+    blocks = [v_stage[j] for j in range(depth)]
+    v_old = functools.reduce(jnp.minimum, [gv[j].read(blocks[j])
+                                           for j in range(depth)])
+    dv = (1.0 - b2) * (g_rows * g_rows - v_old) * valid
     for j in range(depth):
-        v_scr[j] = v_scr[j] + jax.lax.dot(
-            eq_v[j], dv, preferred_element_type=jnp.float32)
+        v_stage[j] = gv[j].add(blocks[j], dv)
     v_new = jnp.maximum(v_old + dv, 0.0)
 
     upd_out[:, :] = (valid * (-lr) * mhat /
                      (jnp.sqrt(v_new / bc2) + eps)).astype(upd_out.dtype)
 
-    # ---- DMA back (duplicate buckets write identical accumulated rows) ----
+    # ---- DMA back (shared groups write identical accumulated rows) -------
     copies = []
-    if track_m:
-        for j in range(depth):
-            for r in range(tile):
-                copies.append(pltpu.async_copy(
-                    m_scr.at[j, pl.ds(r, 1)],
-                    M_out.at[j, pl.ds(bm_ref[j, base + r], 1), :], sem))
     for j in range(depth):
-        for r in range(tile):
-            copies.append(pltpu.async_copy(
-                v_scr.at[j, pl.ds(r, 1)],
-                V_out.at[j, pl.ds(bv_ref[j, base + r], 1), :], sem))
+        if track_m:
+            copies += rg.dma_groups(M_out, m_stage, j, gm[j], sem,
+                                    to_hbm=True)
+        copies += rg.dma_groups(V_out, v_stage, j, gv[j], sem, to_hbm=True)
     for c in copies:
         c.wait()
+
+
+def _one_call(M, V, bm, sm, bv, nv, g, hyper, *, tile, track_m, interpret):
+    depth, w, d = V.shape
+    k = g.shape[0]
+    rows = tile * rg.group_rows(jnp.float32)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,      # bm, sm, bv, n_valid
+        grid=(k // tile,),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),          # hyper
+            pl.BlockSpec((tile, d), lambda t, *_: (t, 0)),  # grad tile
+            pl.BlockSpec(memory_space=pl.ANY),              # M (HBM)
+            pl.BlockSpec(memory_space=pl.ANY),              # V (HBM)
+        ],
+        out_specs=[
+            pl.BlockSpec(memory_space=pl.ANY),              # M'
+            pl.BlockSpec(memory_space=pl.ANY),              # V'
+            pl.BlockSpec((tile, d), lambda t, *_: (t, 0)),  # updates
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((depth if track_m else 1, rows, d), jnp.float32),
+            pltpu.VMEM((depth, rows, d), jnp.float32),
+            pltpu.SemaphoreType.DMA,
+        ],
+    )
+    fn = pl.pallas_call(
+        functools.partial(_tiled_kernel, depth, tile, track_m),
+        grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct(M.shape, M.dtype),
+            jax.ShapeDtypeStruct(V.shape, V.dtype),
+            jax.ShapeDtypeStruct((k, d), jnp.float32),
+        ],
+        # alias M (operand 6 = 4 prefetch + hyper + g) and V (operand 7)
+        input_output_aliases={6: 0, 7: 1},
+        name="cs_adam_tiled",
+        interpret=interpret,
+    )
+    return fn(bm, sm, bv, nv, hyper, g, M, V)
 
 
 def cs_adam_tiled(M: Optional[jnp.ndarray], V: jnp.ndarray,
@@ -167,6 +182,7 @@ def cs_adam_tiled(M: Optional[jnp.ndarray], V: jnp.ndarray,
     tile:   rows per grid step; ``k`` must be a multiple (use
         ``dedup.pad_to_multiple``).
 
+    Sketches are float32 with a width that is a multiple of 8 rows.
     ``M``/``bm``/``sm`` may be None for the β₁=0 (RMSProp) variant.
     """
     depth, w, d = V.shape
@@ -175,47 +191,38 @@ def cs_adam_tiled(M: Optional[jnp.ndarray], V: jnp.ndarray,
         raise ValueError(f"k={k} must be a multiple of tile={tile} "
                          "(pad with dedup.pad_to_multiple)")
     track_m = M is not None
+    for S in ((M, V) if track_m else (V,)):
+        why = rg.kernel_refusal(S.shape[1], S.dtype)
+        if why is None and S.dtype != jnp.float32:
+            why = f"{S.dtype} cells (this kernel keeps float32)"
+        if why is not None:
+            raise ValueError(f"cs_adam_tiled cannot run this sketch: {why}")
     if not track_m:
-        # keep the kernel signature static: feed V twice, ignore the M slots
-        M_in, bm_in, sm_in = V, bv, jnp.ones_like(bv, jnp.float32)
+        # keep the kernel signature static: a one-row dummy M, never DMA'd
+        M, bm, sm = (jnp.zeros((1, 8, d), jnp.float32),
+                     jnp.zeros_like(bv), jnp.ones_like(bv, jnp.float32))
     else:
-        M_in, bm_in, sm_in = M, bm, sm.astype(jnp.float32)
+        sm = sm.astype(jnp.float32)
 
     hyper = jnp.array([lr, b1, b2, eps, bc1, bc2], jnp.float32)
-    nv = jnp.asarray(k if n_valid is None else n_valid,
-                     jnp.int32).reshape((1,))
+    n_valid = jnp.asarray(k if n_valid is None else n_valid, jnp.int32)
+    per, n_calls = rg.split_calls(k, rg.rows_per_call(depth, 3, tile), tile)
+    pad = per * n_calls - k
+    if pad:
+        bm, bv = (jnp.pad(a, ((0, 0), (0, pad))) for a in (bm, bv))
+        sm = jnp.pad(sm, ((0, 0), (0, pad)), constant_values=1.0)
+        g = jnp.pad(g, ((0, pad), (0, 0)))
+    starts = jnp.arange(n_calls, dtype=jnp.int32) * per
+    nv = jnp.clip(n_valid - starts, 0, per)[:, None]
+    xs = (rg.chunk_rows(bm, n_calls, 1), rg.chunk_rows(sm, n_calls, 1),
+          rg.chunk_rows(bv, n_calls, 1), nv, rg.chunk_rows(g, n_calls, 0))
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,      # bm, sm, bv, n_valid
-        grid=(k // tile,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),          # hyper
-            pl.BlockSpec((tile, d), lambda t, *_: (t, 0)),  # grad tile
-            pl.BlockSpec(memory_space=pl.ANY),              # M (HBM)
-            pl.BlockSpec(memory_space=pl.ANY),              # V (HBM)
-        ],
-        out_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),              # M'
-            pl.BlockSpec(memory_space=pl.ANY),              # V'
-            pl.BlockSpec((tile, d), lambda t, *_: (t, 0)),  # updates
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((depth, tile, d), jnp.float32),
-            pltpu.VMEM((depth, tile, d), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    fn = pl.pallas_call(
-        functools.partial(_tiled_kernel, depth, tile, track_m),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct(M_in.shape, M_in.dtype),
-            jax.ShapeDtypeStruct(V.shape, V.dtype),
-            jax.ShapeDtypeStruct((k, d), jnp.float32),
-        ],
-        # alias M (operand 6 = 4 prefetch + hyper + g) and V (operand 7)
-        input_output_aliases={6: 0, 7: 1},
-        interpret=interpret,
-    )
-    M_out, V_out, upd = fn(bm_in, sm_in, bv, nv, hyper, g, M_in, V)
-    return (M_out if track_m else None), V_out, upd
+    def call(carry, c):
+        bm_c, sm_c, bv_c, nv_c, g_c = c
+        M_c, V_c, upd = _one_call(carry[0], carry[1], bm_c, sm_c, bv_c,
+                                  nv_c, g_c, hyper, tile=tile,
+                                  track_m=track_m, interpret=interpret)
+        return (M_c, V_c), upd
+
+    (M, V), upd = rg.scan_calls(call, (M, V), xs, n_calls)
+    return (M if track_m else None), V, upd.reshape(n_calls * per, d)[:k]

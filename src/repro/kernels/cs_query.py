@@ -26,11 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
-
-def _median3(a, b, c):
-    hi = jnp.maximum(jnp.maximum(a, b), c)
-    lo = jnp.minimum(jnp.minimum(a, b), c)
-    return a + b + c - hi - lo
+from repro.core.sketch import median_rows
 
 
 def _query_kernel(depth: int, signed: bool, b_ref, *refs):
@@ -43,13 +39,8 @@ def _query_kernel(depth: int, signed: bool, b_ref, *refs):
         rows = [rows[j] * sign_ref[j, 0] for j in range(depth)]
     else:
         out_ref = refs[depth]
-    if depth == 1:
-        red = rows[0]
-    elif signed:
-        if depth == 3:
-            red = _median3(*rows)
-        else:
-            red = jnp.median(jnp.stack(rows), axis=0)
+    if signed:
+        red = median_rows(rows)
     else:
         red = functools.reduce(jnp.minimum, rows)
     out_ref[0, :] = red.astype(out_ref.dtype)

@@ -1,9 +1,10 @@
 """Jit'd wrappers for the sketch kernels.
 
-Dispatch policy: Pallas kernels on TPU backends, pure-jnp oracles
-(``ref.py`` — identical semantics) elsewhere, so the same model code runs
-on this CPU container, in tests, and on real v5e pods.  ``force`` overrides
-for kernel tests (interpret mode) and benchmarks.
+Each function runs exactly the implementation its name (or registry
+backend) says: a Pallas kernel compiled for the TPU, the same kernel under
+the Pallas interpreter only when asked for by name ('interpret' backend,
+``interpret=True``, ``force='interpret'``), or a pure-jnp form.  Which
+backend serves a sketch is decided once, by ``registry.resolve``.
 """
 from __future__ import annotations
 
@@ -25,8 +26,16 @@ from repro.kernels.cs_query import cs_query
 from repro.kernels.cs_update import cs_update
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _use_kernel(force: Optional[str]) -> Tuple[bool, bool]:
+    """(run the Pallas kernel, under the interpreter) for ``force``:
+    None = the compiled kernel on a TPU host and the jnp oracle elsewhere,
+    'pallas' = the compiled kernel, 'interpret' = the interpreter."""
+    if force not in (None, "pallas", "interpret"):
+        raise ValueError(f"force={force!r}: expected None, 'pallas' or "
+                         "'interpret'")
+    if force is None:
+        return jax.default_backend() == "tpu", False
+    return True, force == "interpret"
 
 
 def _lowp(spec: SketchSpec) -> bool:
@@ -43,26 +52,30 @@ def _addressing(spec: SketchSpec, ids: jnp.ndarray):
 
 def sketch_query(spec: SketchSpec, S: jnp.ndarray, ids: jnp.ndarray, *,
                  force: Optional[str] = None) -> jnp.ndarray:
-    """QUERY rows ``ids``; Pallas gather kernel on TPU, jnp gather off-TPU."""
+    """QUERY rows ``ids``; Pallas gather kernel on TPU, jnp gather off-TPU
+    (``force``: see ``_use_kernel``)."""
     if _lowp(spec):
         # low-precision cells: the core gather dequantizes in-register
         return cs.query(spec, S, ids)
     buckets, signs = _addressing(spec, ids)
-    if force == "pallas" or (force is None and _on_tpu()):
-        return cs_query(S, buckets, signs, interpret=not _on_tpu())
+    kernel, interpret = _use_kernel(force)
+    if kernel:
+        return cs_query(S, buckets, signs, interpret=interpret)
     return ref.cs_query_ref(S, buckets, signs)
 
 
 def sketch_update(spec: SketchSpec, S: jnp.ndarray, ids: jnp.ndarray,
                   delta: jnp.ndarray, *,
                   force: Optional[str] = None) -> jnp.ndarray:
-    """UPDATE rows ``ids`` with ``delta``; sorted-scatter kernel on TPU."""
+    """UPDATE rows ``ids`` with ``delta``; sorted-scatter kernel on TPU
+    (``force``: see ``_use_kernel``)."""
     if _lowp(spec):
         # low-precision cells: stochastic-rounding write in the core
         return cs.update(spec, S, ids, delta)
     buckets, signs = _addressing(spec, ids)
-    if force == "pallas" or (force is None and _on_tpu()):
-        return cs_update(S, buckets, signs, delta, interpret=not _on_tpu())
+    kernel, interpret = _use_kernel(force)
+    if kernel:
+        return cs_update(S, buckets, signs, delta, interpret=interpret)
     return ref.cs_update_ref(S, buckets, signs, delta)
 
 
@@ -106,18 +119,13 @@ def adam_rows_stream(spec_m: Optional[SketchSpec], spec_v: SketchSpec,
                      M: Optional[jnp.ndarray], V: jnp.ndarray,
                      ids: jnp.ndarray, g: jnp.ndarray, step: jnp.ndarray, *,
                      lr, b1: float = 0.9, b2: float = 0.999,
-                     eps: float = 1e-8, interpret: Optional[bool] = None
+                     eps: float = 1e-8, interpret: bool = False
                      ) -> Tuple[Optional[jnp.ndarray], jnp.ndarray, jnp.ndarray]:
     """'stream' backend: one-item-per-grid-step Pallas kernel — exact
-    per-item semantics, sequential over the batch.  Low-precision cells
-    delegate to 'xla' (see ``adam_rows_ref``)."""
-    if _lowp(spec_v) or (spec_m is not None and _lowp(spec_m)):
-        return adam_rows_xla(spec_m, spec_v, M, V, ids, g, step, lr=lr,
-                             b1=b1, b2=b2, eps=eps)
+    per-item semantics, sequential over the batch.  float32 cells only
+    (the registry refuses others)."""
     bm, sm, bv = _adam_addressing(spec_m, spec_v, ids)
     eta, bc1, bc2 = _adam_hypers(step, lr, b1, b2)
-    if interpret is None:
-        interpret = not _on_tpu()
     return cs_adam_fused(M, V, bm, sm, bv, g, lr=eta, b1=b1, b2=b2,
                          eps=eps, bc1=bc1, bc2=bc2, interpret=interpret)
 
@@ -162,8 +170,7 @@ def adam_rows_tiled(spec_m: Optional[SketchSpec], spec_v: SketchSpec,
                     M: Optional[jnp.ndarray], V: jnp.ndarray,
                     ids: jnp.ndarray, g: jnp.ndarray, step: jnp.ndarray, *,
                     lr, b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8,
-                    tile: int = DEFAULT_TILE,
-                    interpret: Optional[bool] = None
+                    tile: int = DEFAULT_TILE, interpret: bool = False
                     ) -> Tuple[Optional[jnp.ndarray], jnp.ndarray, jnp.ndarray]:
     """'tiled' backend: dedup + segment-sum pre-pass, then the batch-parallel
     ``cs_adam_tiled`` kernel over TILE collision-free rows per grid step.
@@ -171,22 +178,16 @@ def adam_rows_tiled(spec_m: Optional[SketchSpec], spec_v: SketchSpec,
     Duplicate ids are merged up front (their gradient rows are what a dense
     gradient would have summed anyway); the resulting updates are scattered
     back so that only the FIRST occurrence of each id carries the update —
-    ``params.at[ids].add(upd)`` applies it exactly once.
+    ``params.at[ids].add(upd)`` applies it exactly once.  float32 cells
+    only; the registry sends other sketches to 'xla' by name
+    (``tiled_refusal``).
     """
-    if _lowp(spec_v) or (spec_m is not None and _lowp(spec_m)):
-        # quantized cells: the tiled kernel's VMEM scratch is f32 and its
-        # touched-rows view cannot refresh per-block absmax scales; the
-        # batch 'xla' form reads/writes the quantized cells directly
-        return adam_rows_xla(spec_m, spec_v, M, V, ids, g, step, lr=lr,
-                             b1=b1, b2=b2, eps=eps)
     if ids.shape[0] == 0:
         return M, V, jnp.zeros(g.shape, jnp.float32)
     eta, bc1, bc2 = _adam_hypers(step, lr, b1, b2)
     with jax.named_scope("obs.dedup"):
         batch = dd.pad_to_multiple(dd.dedup_rows(ids, g), tile)
         bm, sm, bv = _adam_addressing(spec_m, spec_v, batch.unique_ids)
-    if interpret is None:
-        interpret = not _on_tpu()
     with jax.named_scope("obs.kernel"):
         M_out, V_out, upd_u = cs_adam_tiled(
             M, V, bm, sm, bv, batch.rows, lr=eta, b1=b1, b2=b2, eps=eps,
@@ -370,29 +371,25 @@ def ema_update_read_xla(spec: SketchSpec, S: jnp.ndarray, ids: jnp.ndarray,
 def ema_update_read_tiled(spec: SketchSpec, S: jnp.ndarray, ids: jnp.ndarray,
                           x: jnp.ndarray, *, beta: float, scale: float,
                           mask: Optional[jnp.ndarray] = None,
-                          tile: int = EMA_TILE,
-                          interpret: Optional[bool] = None,
+                          tile: int = EMA_TILE, interpret: bool = False,
                           sr_seed=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """'tiled' backend: the ``cs_ema_tiled`` Pallas kernel — TILE rows per
-    sequential grid step, sketch rows DMA'd from HBM in one overlapped
-    burst per tile.  Batch semantics within a tile, streaming across
-    tiles (exact vs 'ref' when no two rows share a bucket; estimator-
-    noise tolerance otherwise — DESIGN.md §14).
+    sequential grid step, sketch row groups DMA'd from HBM in one
+    overlapped burst per tile.  Batch semantics within a tile, streaming
+    across tiles (exact vs 'ref' when no two rows share a bucket;
+    estimator-noise tolerance otherwise — DESIGN.md §14).
 
-    bf16 cells run IN the kernel: rows DMA in/out as bf16, compute is
-    f32 in VMEM, and write-back stochastically re-rounds with the same
+    bf16 cells run IN the kernel: row groups DMA in/out as bf16, compute
+    is f32 in VMEM, and write-back stochastically re-rounds with the same
     counter-hash bits the xla path derives — touched rows match 'xla'
-    bit-for-bit on collision-free row sets.  int8 cells fall back to
-    'xla': per-(depth, block) absmax scale refresh needs a whole-sketch
-    view a touched-rows kernel doesn't have (DESIGN.md §18)."""
-    if spec.quantized:
-        return ema_update_read_xla(spec, S, ids, x, beta=beta, scale=scale,
-                                   mask=mask, sr_seed=sr_seed)
+    bit-for-bit on collision-free row sets.  int8 cells, and rows that
+    are not whole 128-lane tiles, are refused by the registry, which
+    sends them to 'xla' by name: per-(depth, block) absmax scale refresh
+    needs a whole-sketch view a touched-rows kernel doesn't have
+    (DESIGN.md §18)."""
     k = int(ids.shape[0])
     if k == 0:
         return S, jnp.zeros(x.shape, jnp.float32)
-    if interpret is None:
-        interpret = not _on_tpu()
     seed = None
     if jnp.dtype(spec.dtype) == jnp.bfloat16:
         seed = cs.sr_seed_or_default(spec, sr_seed)
@@ -460,22 +457,3 @@ def slab_gather_xla(spec: SketchSpec, slab: jnp.ndarray, ids: jnp.ndarray,
         rows.append(jnp.where(own[j][:, None], r,
                               jnp.zeros((), dtype=slab.dtype)))
     return jnp.stack(rows)
-
-
-def adam_rows_fused(spec_m: Optional[SketchSpec], spec_v: SketchSpec,
-                    M: Optional[jnp.ndarray], V: jnp.ndarray,
-                    ids: jnp.ndarray, g: jnp.ndarray,
-                    step: jnp.ndarray, *, lr, b1: float, b2: float,
-                    eps: float, force: Optional[str] = None
-                    ) -> Tuple[Optional[jnp.ndarray], jnp.ndarray, jnp.ndarray]:
-    """Streaming fused CS-Adam over ``k`` rows (paper Alg. 4 semantics).
-
-    Pallas single-pass kernel on TPU, ``lax.scan`` oracle elsewhere.
-    Kept for callers that want the exact per-item semantics regardless of
-    the registry's backend selection."""
-    if force == "pallas" or (force is None and _on_tpu()):
-        return adam_rows_stream(spec_m, spec_v, M, V, ids, g, step, lr=lr,
-                                b1=b1, b2=b2, eps=eps,
-                                interpret=not _on_tpu())
-    return adam_rows_ref(spec_m, spec_v, M, V, ids, g, step, lr=lr,
-                         b1=b1, b2=b2, eps=eps)

@@ -19,16 +19,11 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.core.sketch import median_rows
+
 
 def _median_depth(vals: jnp.ndarray) -> jnp.ndarray:
-    v = vals.shape[0]
-    if v == 1:
-        return vals[0]
-    if v == 3:
-        hi = jnp.maximum(jnp.maximum(vals[0], vals[1]), vals[2])
-        lo = jnp.minimum(jnp.minimum(vals[0], vals[1]), vals[2])
-        return vals[0] + vals[1] + vals[2] - hi - lo
-    return jnp.median(vals, axis=0)
+    return median_rows([vals[i] for i in range(vals.shape[0])])
 
 
 def cs_query_ref(S: jnp.ndarray, buckets: jnp.ndarray,

@@ -24,15 +24,27 @@ port) attach via ``register``.
 ``kernels/__init__.py`` keeps the PR-1 flat API (``register_backend`` /
 ``backends()`` / ``resolve_backend`` / ``adam_rows``) as thin wrappers
 over the ('pair', 'adam_rows') row of this registry.
+
+A backend may register a ``refusal(spec) -> Optional[str]``: the reason
+it cannot run a sketch (the Pallas kernels need whole 128-lane rows and
+f32/bf16 cells; see ``row_groups.tiled_refusal``).  ``resolve`` with the
+op's specs then sends None/'auto' to 'xla' for such a sketch — by name,
+so what runs is what ``resolve`` returns — and raises for an explicit
+name.  ``recording()`` collects every spec-bearing resolution made while
+it is open (the launcher reports what its compiled step runs).
 """
 from __future__ import annotations
 
-from typing import Callable, Dict, Optional, Tuple
+import contextlib
+from typing import Callable, Dict, Iterator, List, Optional, Tuple
 
 import jax
 
 # (kind, op) -> {backend name: fn}, insertion-ordered per row.
 _REGISTRY: Dict[Tuple[str, str], Dict[str, Callable]] = {}
+# (kind, op, backend) -> refusal(spec) -> reason | None
+_REFUSALS: Dict[Tuple[str, str, str], Callable] = {}
+_RECORDERS: List[list] = []
 
 # Per-platform default picked by resolve(..., None/'auto'): the Pallas
 # tiled pipeline on TPU, the vectorized jnp path everywhere else.
@@ -40,9 +52,14 @@ _AUTO = {"tpu": "tiled"}
 _AUTO_FALLBACK = "xla"
 
 
-def register(kind: str, op: str, backend: str, fn: Callable) -> None:
-    """Register (or override) one implementation of ``op`` for ``kind``."""
+def register(kind: str, op: str, backend: str, fn: Callable,
+             refusal: Optional[Callable] = None) -> None:
+    """Register (or override) one implementation of ``op`` for ``kind``;
+    ``refusal(spec)`` names why it cannot run a sketch (None: it can)."""
     _REGISTRY.setdefault((kind, op), {})[backend] = fn
+    _REFUSALS.pop((kind, op, backend), None)
+    if refusal is not None:
+        _REFUSALS[(kind, op, backend)] = refusal
 
 
 def ops() -> Tuple[Tuple[str, str], ...]:
@@ -59,17 +76,62 @@ def backends(kind: str, op: str) -> Tuple[str, ...]:
     return tuple(row)
 
 
-def resolve(kind: str, op: str, backend: Optional[str] = None) -> str:
-    """Map None/'auto' to this host's best backend for (kind, op);
-    validate explicit names against the registered row."""
+def refusal(kind: str, op: str, backend: str, specs=()) -> Optional[str]:
+    """Why ``backend`` cannot run (kind, op) on these sketch specs (None
+    when it can, or when it registered no refusal)."""
+    fn = _REFUSALS.get((kind, op, backend))
+    for spec in specs:
+        why = None if fn is None or spec is None else fn(spec)
+        if why is not None:
+            return why
+    return None
+
+
+def resolve(kind: str, op: str, backend: Optional[str] = None,
+            specs=()) -> str:
+    """Map None/'auto' to this host's best backend for (kind, op) — 'xla'
+    for a sketch the best one refuses; validate explicit names against
+    the registered row and against ``specs``."""
     names = backends(kind, op)
     if backend is None or backend == "auto":
-        best = _AUTO.get(jax.default_backend(), _AUTO_FALLBACK)
-        return best if best in names else names[0]
-    if backend not in names:
+        name = _AUTO.get(jax.default_backend(), _AUTO_FALLBACK)
+        if name not in names:
+            name = names[0]
+        if refusal(kind, op, name, specs) is not None:
+            name = _AUTO_FALLBACK
+    elif backend not in names:
         raise KeyError(f"unknown backend {backend!r} for kind={kind!r} "
                        f"op={op!r}; registered: {names}")
-    return backend
+    else:
+        name = backend
+        why = refusal(kind, op, name, specs)
+        if why is not None:
+            raise ValueError(
+                f"backend {name!r} cannot run {kind}/{op} here: {why} — "
+                f"pass 'auto' to let the registry pick, or 'xla'")
+    if specs:
+        for rec in _RECORDERS:
+            rec.append((kind, op, name, tuple(
+                _describe(s) for s in specs if s is not None)))
+    return name
+
+
+def _describe(spec) -> str:
+    return (f"{spec.depth}x{spec.width}x{spec.dim} "
+            f"{getattr(spec.dtype, 'name', spec.dtype)}")
+
+
+@contextlib.contextmanager
+def recording() -> Iterator[list]:
+    """Collect ``(kind, op, backend, spec descriptions)`` for every
+    spec-bearing ``resolve`` made inside the block (typically while a
+    step traces)."""
+    rec: list = []
+    _RECORDERS.append(rec)
+    try:
+        yield rec
+    finally:
+        _RECORDERS.remove(rec)
 
 
 def lookup(kind: str, op: str, backend: Optional[str] = None) -> Callable:

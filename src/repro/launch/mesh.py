@@ -7,8 +7,7 @@ sets XLA_FLAGS before any import; tests/benches must see 1 device).
 from __future__ import annotations
 
 import jax
-
-from repro.distributed.sharding import make_mesh_compat
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -18,7 +17,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     (outer DP + FSDP for 400B-class models) when ``multi_pod``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh_compat(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -26,7 +25,8 @@ def make_host_mesh(data: int = 1, model: int = 1):
     CPU integration tests."""
     n = len(jax.devices())
     data = min(data, n // model) or 1
-    return make_mesh_compat((data, model), ("data", "model"))
+    return jax.make_mesh((data, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 # v5e hardware constants for the roofline terms (per chip).

@@ -21,9 +21,18 @@ Distributed data parallelism (DESIGN.md §13):
     ``--dp`` the gradient collective moves (depth, width, dim) COUNT
     SKETCHES instead of the (k, d) rows, and the sketch state itself is
     stored width-sharded over 'data' (``sharding.opt_specs_for_state``).
+
+Every training workload compiles its step once before the loop and
+prints the compile time, the kernel backend each sketched table resolved
+to, and XLA's memory analysis.  ``run(argv)`` returns that with the loss
+history as a ``RunReport``; ``main(argv)`` returns its exit code — 0 only
+when the loss is finite and fell.
 """
 import argparse
+import dataclasses
 import os
+import time
+from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -32,11 +41,58 @@ from repro import configs
 from repro.checkpoint import store
 from repro.data import ZipfLM, ZipfLMConfig
 from repro.distributed import sharding as shd
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.obs import (MetricsWriter, PhaseTimer, RunObserver, maybe_trace)
 from repro.train.steps import (make_sparse_embedding_step, make_train_step,
                                sparse_embedding_stores)
 from repro.train.trainer import Trainer, TrainerConfig, TrainState
+
+
+@dataclasses.dataclass
+class RunReport:
+    """What one launcher run did: its exit code, the per-step records of
+    its (last) training loop, and the compiled step's report."""
+    rc: int
+    history: List[dict] = dataclasses.field(default_factory=list)
+    compile_s: Optional[float] = None
+    # (kind, op, backend, sketch shapes) resolved while the step traced
+    backends: Tuple[tuple, ...] = ()
+    memory: Any = None            # compiled.memory_analysis()
+
+
+def loss_fell(hist, w: int) -> bool:
+    """Finite losses whose last ``w``-step mean is below the first's."""
+    losses = np.array([h["loss"] for h in hist], np.float64)
+    if losses.size == 0 or not np.isfinite(losses).all():
+        return False
+    return losses[-w:].mean() < losses[:w].mean()
+
+
+def compile_step(jit_step, *args):
+    """Lower and compile ``jit_step`` for ``args`` (placed as the step
+    expects them) before the loop.  Prints the compile time, the backend
+    each sketched table resolved to, and the memory analysis; returns
+    the executable and a ``RunReport`` with those fields filled in."""
+    from repro.kernels import registry
+    t0 = time.perf_counter()
+    with registry.recording() as rec:
+        compiled = jit_step.lower(*args).compile()
+    dt = time.perf_counter() - t0
+    backends = tuple(sorted(set(rec)))
+    mem = compiled.memory_analysis()
+    print(f"[compile] step compiled in {dt:.2f} s", flush=True)
+    for kind, op, name, specs in backends:
+        print(f"[compile] {kind}/{op} -> {name} ({', '.join(specs)})",
+              flush=True)
+    if mem is not None:
+        print(f"[compile] memory: arguments "
+              f"{mem.argument_size_in_bytes:,} B, outputs "
+              f"{mem.output_size_in_bytes:,} B, aliased "
+              f"{mem.alias_size_in_bytes:,} B, temporaries "
+              f"{mem.temp_size_in_bytes:,} B", flush=True)
+    return compiled, RunReport(rc=1, compile_s=dt, backends=backends,
+                               memory=mem)
 
 
 def make_observer(args, run_meta, monitors=(), subdir: str = ""):
@@ -183,7 +239,10 @@ def run_sparse_embedding(args, mesh) -> int:
             "labels": shd.batch_spec(mesh, (args.batch, args.seq))})
         mspec = NamedSharding(mesh, P())
 
-        def train_step(table, opt_state, batch):
+        # the target is an argument, not a closed-over constant: baked
+        # into the program, a 512 MiB table slows the compile and bloats
+        # every executable (and its compile-cache entry) by its size
+        def train_step(table, opt_state, batch, target):
             ids = batch["tokens"].reshape(-1).astype(jnp.int32)
             rows = table[ids] - target[ids]
             loss = jnp.mean(jnp.square(rows))
@@ -199,18 +258,29 @@ def run_sparse_embedding(args, mesh) -> int:
             return table, inner, {"loss": loss, "grad_norm": gn}
 
         jit_step = jax.jit(train_step,
-                           in_shardings=(table_spec, opt_spec, bspec),
+                           in_shardings=(table_spec, opt_spec, bspec,
+                                         table_spec),
                            out_shardings=(table_spec, opt_spec, mspec),
                            donate_argnums=(0, 1))
         tcfg = TrainerConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
                              ckpt_every=args.ckpt_every,
                              log_every=args.log_every)
-        trainer = Trainer(jit_step, data, tcfg, observer=observer,
+        state = TrainState(step=0, params=table, opt_state=opt_state)
+        trainer = Trainer(None, data, tcfg, observer=observer,
                           store_tree=run_tree, cleaner=cleaner)
         state = trainer.restore_or_init(
-            TrainState(step=0, params=table, opt_state=opt_state),
-            shardings=({"params": table_spec, "opt_state": opt_spec}
-                       if shards > 1 else None))
+            state, shardings=({"params": table_spec, "opt_state": opt_spec}
+                              if shards > 1 else None))
+        state = dataclasses.replace(
+            state, params=jax.device_put(state.params, table_spec),
+            opt_state=jax.device_put(state.opt_state, opt_spec))
+        target = jax.device_put(target, table_spec)
+        compiled, report = compile_step(
+            jit_step, state.params, state.opt_state,
+            jax.tree_util.tree_map(np.asarray, data.batch(state.step)),
+            target)
+        trainer.step_fn = lambda table, opt_state, batch: compiled(
+            table, opt_state, batch, target)
         with maybe_trace(args.profile_dir):
             state = trainer.fit(state)
 
@@ -226,7 +296,8 @@ def run_sparse_embedding(args, mesh) -> int:
           f"dp={bool(args.dp)} shards={shards}({layout}) "
           f"feedback={bool(args.error_feedback)} "
           f"steps={state.step} loss {first:.4f} -> {last:.4f}")
-    return 0 if last < first else 1
+    return dataclasses.replace(report, rc=0 if loss_fell(hist, w) else 1,
+                               history=hist)
 
 
 def run_serve_replay(args, mesh) -> int:
@@ -281,7 +352,7 @@ def run_serve_replay(args, mesh) -> int:
           f"batches={server.n_batches} shed={server.shed_rate:.3f} "
           f"adapt p50 {h['p50_ms']:.2f} ms p99 {h['p99_ms']:.2f} ms "
           f"adapts/s {rec['reads_per_s']:.1f}")
-    return 0 if server.n_done > 0 else 1
+    return RunReport(rc=0 if server.n_done > 0 else 1)
 
 
 class _MetaStream:
@@ -361,6 +432,7 @@ def run_extreme(args, mesh) -> int:
 
     cmaps = cfg.class_maps()
     finals = []
+    report = None
     with shd.active_mesh(mesh):
         jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
         for r in range(cfg.n_replicas):
@@ -379,10 +451,17 @@ def run_extreme(args, mesh) -> int:
                 "optimizer": args.optimizer, "batch": args.batch,
                 "dp": bool(args.dp)}, replica_monitors(),
                 subdir=f"replica{r}")
-            trainer = Trainer(jit_step, data, tcfg, plan=plan,
+            trainer = Trainer(None, data, tcfg, plan=plan,
                               observer=observer)
             state = trainer.restore_or_init(
                 TrainState(step=0, params=params, opt_state=opt_state))
+            if report is None:
+                # one executable serves every replica (same shapes)
+                compiled, report = compile_step(
+                    jit_step, state.params, state.opt_state,
+                    jax.tree_util.tree_map(np.asarray,
+                                           data.batch(state.step)))
+            trainer.step_fn = compiled
             with maybe_trace(args.profile_dir if r == 0 else None):
                 state = trainer.fit(state)
             hist = trainer.history
@@ -390,7 +469,7 @@ def run_extreme(args, mesh) -> int:
             w = max(1, min(10, len(hist) // 3))
             first = np.mean([h["loss"] for h in hist[:w]])
             last = np.mean([h["loss"] for h in hist[-w:]])
-            finals.append((first, last))
+            finals.append((loss_fell(hist, w), first, last))
             print(f"[train] workload=extreme replica={r} "
                   f"steps={state.step} loss {first:.4f} -> {last:.4f}",
                   flush=True)
@@ -398,11 +477,20 @@ def run_extreme(args, mesh) -> int:
           f"meta_rows={cfg.n_meta:,} replicas={cfg.n_replicas} "
           f"optimizer={args.optimizer} dp={bool(args.dp)} "
           f"batch={args.batch} per-replica losses "
-          f"{[round(float(l), 4) for _, l in finals]}")
-    return 0 if all(l < f for f, l in finals) else 1
+          f"{[round(float(l), 4) for _, _, l in finals]}")
+    return dataclasses.replace(
+        report, rc=0 if all(ok for ok, _, _ in finals) else 1,
+        history=hist)
 
 
-def main() -> int:
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    """Command-line entry point: the exit code of ``run(argv)``."""
+    return run(argv).rc
+
+
+def run(argv: Optional[Sequence[str]] = None) -> RunReport:
+    """Parse ``argv`` (default: ``sys.argv[1:]``), run the workload, and
+    report what ran."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2_0_5b")
     ap.add_argument("--reduced", action="store_true",
@@ -532,13 +620,14 @@ def main() -> int:
                          "only — overrides whatever backend a recorded "
                          "plan/manifest carries without touching state "
                          "layout, so restores stay valid")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.probe_rows and not args.metrics_dir:
         ap.error("--probe-rows needs --metrics-dir (probe errors are "
                  "emitted as 'table' metrics records)")
 
     if os.environ.get("JAX_COORDINATOR"):
         jax.distributed.initialize()
+    enable_compile_cache()
 
     if args.sketch_cell_dtype == "int8" and (args.dp
                                              or args.sketch_shards > 1):
@@ -655,13 +744,13 @@ def main() -> int:
         print(f"[plan] store backend -> {args.store_backend}", flush=True)
     elif plan is not None and plan.backend == "tiled" \
             and jax.default_backend() != "tpu":
-        # a recorded 'tiled' backend is a TPU execution knob; restoring
-        # it on a CPU/GPU host would silently run every step through
-        # the Pallas interpreter — fall back to this host's fused path
-        # (state layout unchanged; pass --store-backend to override)
-        print("[plan] recorded store backend 'tiled' needs a TPU; this "
-              f"host is {jax.default_backend()} -> 'xla'", flush=True)
-        plan = plan.with_backend("xla")
+        # a recorded 'tiled' backend is a TPU execution knob: the kernel
+        # compiles for a TPU only (state layout is backend-independent)
+        raise ValueError(
+            f"{args.ckpt_dir}'s plan records store backend 'tiled', which "
+            f"compiles for a TPU only; this host is "
+            f"{jax.default_backend()} — resume with --store-backend auto "
+            f"(or xla)")
     ts = make_train_step(cfg, optimizer=args.optimizer, lr=args.lr,
                          plan=plan, dp_axis="data" if args.dp else None,
                          kernel_backend=args.store_backend or None)
@@ -699,14 +788,17 @@ def main() -> int:
                              ckpt_every=args.ckpt_every,
                              log_every=args.log_every)
 
-        def wrapped_step(params, opt_state, batch):
+        def wrapped_batch(batch):
             if cfg.family == "encdec":
                 batch = dict(batch, frames=jax.numpy.zeros(
                     (args.batch, cfg.enc_seq, cfg.d_model), cfg.dtype))
             if cfg.family == "vlm":
                 batch = dict(batch, patches=jax.numpy.zeros(
                     (args.batch, cfg.n_patches, cfg.d_model), cfg.dtype))
-            return step_fn(params, opt_state, batch)
+            return batch
+
+        def wrapped_step(params, opt_state, batch):
+            return step_fn(params, opt_state, wrapped_batch(batch))
 
         observer = make_observer(args, {
             "workload": "lm", "arch": cfg.name, "optimizer": args.optimizer,
@@ -717,17 +809,26 @@ def main() -> int:
         state = trainer.restore_or_init(
             TrainState(step=0, params=params, opt_state=opt_state),
             shardings={"params": pshard, "opt_state": oshard})
+        state = dataclasses.replace(
+            state, params=jax.device_put(state.params, pshard),
+            opt_state=jax.device_put(state.opt_state, oshard))
+        step_fn, report = compile_step(
+            step_fn, state.params, state.opt_state,
+            wrapped_batch(jax.tree_util.tree_map(np.asarray,
+                                                 data.batch(state.step))))
         with maybe_trace(args.profile_dir):
             state = trainer.fit(state)
 
     hist = trainer.history
-    first = np.mean([h["loss"] for h in hist[:10]])
-    last = np.mean([h["loss"] for h in hist[-10:]])
+    # disjoint head/tail windows on short runs too
+    w = min(10, max(1, len(hist) // 2))
+    first = np.mean([h["loss"] for h in hist[:w]])
+    last = np.mean([h["loss"] for h in hist[-w:]])
     print(f"[train] arch={cfg.name} optimizer={args.optimizer} "
           f"dp={bool(args.dp)} steps={state.step} "
-          f"loss {first:.3f} -> {last:.3f} "
-          f"({np.mean([h['time_s'] for h in hist[5:]]):.3f}s/step)")
-    return 0
+          f"loss {first:.3f} -> {last:.3f}")
+    return dataclasses.replace(report, rc=0 if loss_fell(hist, w) else 1,
+                               history=hist)
 
 
 if __name__ == "__main__":

@@ -172,10 +172,8 @@ def moe_apply(cfg: ArchConfig, p, x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndar
 
 
 def _shard(x, axes):
-    """Best-effort sharding constraint — no-op outside a mesh context."""
-    try:
-        from jax.sharding import PartitionSpec as P
-        from repro.distributed.sharding import constraint
-        return constraint(x, P(*axes))
-    except Exception:
-        return x
+    """Sharding constraint — ``sharding.constraint`` is a no-op outside a
+    mesh context and drops axes the mesh lacks or cannot divide."""
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.sharding import constraint
+    return constraint(x, P(*axes))

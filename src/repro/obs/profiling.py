@@ -29,25 +29,9 @@ import numpy as np
 
 
 def scope(name: str):
-    """Named scope for traced (in-jit) code — ``jax.named_scope`` with a
-    no-op fallback so instrumented code never depends on the jax
-    version."""
+    """Named scope for traced (in-jit) code — ``jax.named_scope``."""
     import jax
-    try:
-        return jax.named_scope(name)
-    except Exception:  # noqa: BLE001 — ancient jax: profiling is optional
-        return contextlib.nullcontext()
-
-
-@contextlib.contextmanager
-def _trace_annotation(name: str) -> Iterator[None]:
-    import jax
-    try:
-        ctx = jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001
-        ctx = contextlib.nullcontext()
-    with ctx:
-        yield
+    return jax.named_scope(name)
 
 
 class PhaseTimer:
@@ -66,8 +50,9 @@ class PhaseTimer:
 
     @contextlib.contextmanager
     def phase(self, name: str) -> Iterator[None]:
+        import jax
         t0 = time.perf_counter()
-        with _trace_annotation(name):
+        with jax.profiler.TraceAnnotation(name):
             try:
                 yield
             finally:

@@ -302,12 +302,12 @@ def timed_adapt(adapt_fn, tracker=None, *, capacity: int = 4096):
 
     import jax
 
-    from repro.obs.profiling import LatencyTracker, _trace_annotation
+    from repro.obs.profiling import LatencyTracker
     lat = tracker if tracker is not None else LatencyTracker(capacity)
 
     def wrapped(table, opt_state, ids, grad_rows):
         t0 = time.perf_counter()
-        with _trace_annotation("obs.adapt"):
+        with jax.profiler.TraceAnnotation("obs.adapt"):
             table, opt_state = adapt_fn(table, opt_state, ids, grad_rows)
             jax.block_until_ready((table, opt_state))
         lat.record(time.perf_counter() - t0)

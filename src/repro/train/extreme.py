@@ -262,7 +262,8 @@ def make_extreme_step(cfg: MachConfig, *, optimizer: str = "cs_rmsprop",
         if dp_axis is None:
             opts[path] = opt_lib.sparse_rows_adam(
                 lr, b1=b1, shape=shape, path=path, hparams=hp,
-                track_first_moment=track, m_store=m_store, v_store=v_store)
+                track_first_moment=track, m_store=m_store, v_store=v_store,
+                dir_clip=dir_clip)
         else:
             opts[path] = opt_lib.sparse_rows_adam_dp(
                 lr, b1=b1, shape=shape, path=path, axis_name=dp_axis,
@@ -331,10 +332,11 @@ def make_extreme_step(cfg: MachConfig, *, optimizer: str = "cs_rmsprop",
                     "dp extreme steps need a mesh: pass mesh= or trace "
                     "inside shd.active_mesh(mesh)")
             dp = P(dp_axis)
-            return shd.shard_map_unchecked(
+            return jax.shard_map(
                 local_step, mesh=use_mesh,
                 in_specs=(P(), P(), {"features": dp, "labels": dp,
                                      "negatives": P()}),
-                out_specs=(P(), P(), P()))(params, opt_state, batch)
+                out_specs=(P(), P(), P()),
+                check_vma=False)(params, opt_state, batch)
 
     return init_fn, step_fn, opts

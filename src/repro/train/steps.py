@@ -197,10 +197,10 @@ def make_train_step(cfg: ArchConfig, *, optimizer: str = "cs_adam",
                 with shd.manual_collectives():
                     return step_body(params, opt_state, batch)
 
-            return shd.shard_map_unchecked(
-                inner, mesh=mesh,
-                in_specs=(P(), P(), P(dp_axis)),
-                out_specs=(P(), P(), P()))(params, opt_state, batch)
+            return jax.shard_map(
+                inner, mesh=mesh, in_specs=(P(), P(), P(dp_axis)),
+                out_specs=(P(), P(), P()),
+                check_vma=False)(params, opt_state, batch)
 
     def init_fn(rng):
         return mod.init(rng, cfg)
@@ -315,7 +315,8 @@ def make_sparse_embedding_step(n_rows: int, dim: int, *, lr=1e-3,
     square terms unless ``error_feedback=True`` adds the MicroAdam-style
     residual sketch, and ``dir_clip`` trust-clamps the emitted direction
     against sketch-estimator noise (``sketched_reduce.dp_adam_rows``;
-    None disables).  Sketch state is replicated in the shard_map body;
+    None disables) — on one device too, where the signed-median
+    numerator is just as much an estimate (``scale_by_adam_rows``).  Sketch state is replicated in the shard_map body;
     at the jit level it stores sharded per ``sharding.opt_specs_for_state``
     (width over 'data', dim over 'model').
 
@@ -348,7 +349,8 @@ def make_sparse_embedding_step(n_rows: int, dim: int, *, lr=1e-3,
         opt = opt_lib.sparse_rows_adam(
             lr, b1=b1, b2=b2, eps=eps, shape=(n_rows, dim), path=path,
             hparams=hp, track_first_moment=track_first_moment,
-            cleaning=cleaning, m_store=m_store, v_store=v_store)
+            cleaning=cleaning, m_store=m_store, v_store=v_store,
+            dir_clip=dir_clip)
     else:
         opt = opt_lib.sparse_rows_adam_dp(
             lr, b1=b1, b2=b2, eps=eps, shape=(n_rows, dim), path=path,

@@ -11,8 +11,10 @@ Three tiers of agreement, from exact to statistical:
      that semantics, and within tolerance against ref (the residual is
      median/min estimator noise, quantified here with fixed seeds).
 
-Pallas backends run in interpret mode on CPU (kernel body in Python,
-BlockSpecs/DMAs as on TPU).
+Pallas backends run under the interpreter on CPU, named explicitly:
+the 'interpret' backend, or ``interpret=True`` on the stream kernel
+(kernel body in Python, BlockSpecs/DMAs as on TPU).  'tiled' itself only
+compiles for a TPU (tests/test_chip_compile.py).
 """
 import functools
 
@@ -52,6 +54,12 @@ def _applied(n, d, ids, upd):
 
 def _run(backend, spec_m, spec_v, M, V, ids, g, step=2, **kw):
     kw = {**LR, **kw}
+    if backend == "stream":
+        # the stream kernel under the interpreter (no registry name for it)
+        from repro.kernels import ops
+        return ops.adam_rows_stream(
+            spec_m if M is not None else None, spec_v, M, V, ids, g,
+            jnp.asarray(step, jnp.int32), interpret=True, **kw)
     return K.adam_rows(spec_m if M is not None else None, spec_v,
                        M, V, ids, g, jnp.asarray(step, jnp.int32),
                        backend=backend, **kw)
@@ -117,7 +125,7 @@ def test_tiled_matches_per_item_oracle_collision_free(depth, track_m):
     g = jnp.asarray(rng.randn(k, d), jnp.float32)
     b1 = 0.9 if track_m else 0.0
     r = _run("ref", spec_m, spec_v, M, V, ids, g, b1=b1)
-    for backend in ("xla", "tiled", "interpret"):
+    for backend in ("xla", "interpret"):
         t = _run(backend, spec_m, spec_v, M, V, ids, g, b1=b1)
         for a, b in zip(r, t):
             if a is None:
@@ -144,7 +152,7 @@ def test_tiled_matches_ref_real_hash_no_bucket_collisions(depth):
     M, V = _states(spec_m, spec_v, True, seed=depth)
     g = jnp.asarray(rng.randn(k, d), jnp.float32)
     r = _run("ref", spec_m, spec_v, M, V, ids, g)
-    t = _run("tiled", spec_m, spec_v, M, V, ids, g)
+    t = _run("interpret", spec_m, spec_v, M, V, ids, g)
     for a, b in zip(r, t):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
@@ -222,13 +230,13 @@ def test_tiled_vs_ref_tolerance_under_collisions(depth):
         ids = jnp.asarray(rng.choice(n, k, replace=False), jnp.int32)
         g = jnp.asarray(rng.randn(k, d), jnp.float32)
         _, _, ur = _run("ref", spec_m, spec_v, M, V, ids, g)
-        _, _, ut = _run("tiled", spec_m, spec_v, M, V, ids, g)
+        _, _, ut = _run("interpret", spec_m, spec_v, M, V, ids, g)
         ar, at = _applied(n, d, ids, ur), _applied(n, d, ids, ut)
         worst = max(worst, np.linalg.norm(ar - at) / np.linalg.norm(ar))
     assert worst < 0.6, worst
 
 
-@pytest.mark.parametrize("backend", ["tiled", "xla"])
+@pytest.mark.parametrize("backend", ["interpret", "xla"])
 def test_dedup_backends_apply_duplicates_exactly_once(backend):
     """Duplicate-heavy batch in identity mode: the dedup backends must
     apply, per id, exactly the update of the segment-summed gradient —
@@ -251,7 +259,7 @@ def test_dedup_backends_apply_duplicates_exactly_once(backend):
     np.testing.assert_allclose(a_t, a_m, atol=1e-5)
 
 
-@pytest.mark.parametrize("backend", ["tiled", "xla"])
+@pytest.mark.parametrize("backend", ["interpret", "xla"])
 def test_empty_batch_is_identity(backend):
     n, d = 128, 128
     spec_m, spec_v = _specs(n, d, 3)
@@ -265,23 +273,61 @@ def test_empty_batch_is_identity(backend):
 
 
 def test_sparse_rows_adam_routes_backends():
-    """optimizer-level entry point: same (table, state) trajectory under
-    'interpret' (forced-interpreter tiled) and 'tiled' backends."""
+    """optimizer-level entry point: the named backend is the one that
+    runs, and on a collision-free batch (identity hashing, unique ids)
+    'interpret' (the tiled kernel under the interpreter) and 'xla' give
+    the same (table, state) trajectory."""
     from repro.core import optimizers as O
+    from repro.kernels import registry
     n, d = 512, 128
-    hp_t = O.SketchHParams(compression=4.0, width_multiple=16,
-                           backend="tiled")
-    hp_i = O.SketchHParams(compression=4.0, width_multiple=16,
-                           backend="interpret")
     rng = np.random.RandomState(0)
-    ids = jnp.asarray(rng.randint(0, n, 16), jnp.int32)
+    ids = jnp.asarray(rng.permutation(n)[:16], jnp.int32)
     rows = jnp.asarray(rng.randn(16, d), jnp.float32)
     outs = []
-    for hp in (hp_t, hp_i):
+    for backend in ("interpret", "xla"):
+        hp = O.SketchHParams(compression=1.0, width_multiple=16,
+                             identity=True, backend=backend)
         opt = O.sparse_rows_adam(1e-2, shape=(n, d), hparams=hp)
         state = opt.init()
-        upd, state = opt.update({"ids": ids, "rows": rows}, state)
+        with registry.recording() as rec:
+            upd, state = opt.update({"ids": ids, "rows": rows}, state)
+        assert {r[2] for r in rec} == {backend}
         table = O.apply_sparse_updates(jnp.zeros((n, d)), upd)
         outs.append((np.asarray(table), np.asarray(state["v"])))
     np.testing.assert_allclose(outs[0][0], outs[1][0], atol=1e-6)
     np.testing.assert_allclose(outs[0][1], outs[1][1], atol=1e-6)
+
+
+@pytest.mark.parametrize("kernel", ["adam", "ema"])
+def test_tiled_split_into_calls_matches_one_call(kernel, monkeypatch):
+    """A batch longer than one call's SMEM address budget runs as a scan
+    of calls; call c+1 sees call c's writes exactly as tile t+1 sees tile
+    t's, so the split result equals the one-call result bit-for-bit
+    (heavy collisions, ragged n_valid)."""
+    from repro.kernels import row_groups as rg
+    from repro.kernels.cs_adam_tiled import cs_adam_tiled
+    from repro.kernels.cs_ema_tiled import cs_ema_tiled
+    depth, width, d, k = 3, 16, 128, 40
+    rng = np.random.RandomState(7)
+    M = jnp.asarray(rng.randn(depth, width, d), jnp.float32)
+    V = jnp.abs(jnp.asarray(rng.randn(depth, width, d), jnp.float32))
+    b = jnp.asarray(rng.randint(0, width, (depth, k)), jnp.int32)
+    s = jnp.asarray(rng.choice([-1.0, 1.0], (depth, k)), jnp.float32)
+    g = jnp.asarray(rng.randn(k, d), jnp.float32)
+
+    def run():
+        if kernel == "adam":
+            return cs_adam_tiled(M, V, b, s, b[::-1], g, lr=1e-2, b1=0.9,
+                                 b2=0.999, eps=1e-8, bc1=0.19, bc2=0.002,
+                                 n_valid=k - 5, interpret=True)
+        return cs_ema_tiled(M, b, s, g, jnp.ones((k, 1), jnp.float32),
+                            beta=0.9, scale=0.1, n_valid=k - 5,
+                            interpret=True)
+
+    whole = run()
+    # 3 tables x 8 padded rows x 4 B x 16 rows: at most 16 rows per call
+    monkeypatch.setattr(rg, "_SMEM_BUDGET", 3 * 8 * 4 * 16)
+    assert rg.split_calls(k, rg.rows_per_call(depth, 3, 8), 8) == (16, 3)
+    split = run()
+    for a, c in zip(whole, split):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(c))

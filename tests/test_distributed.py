@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.distributed import sharding as shd
 from repro.distributed.elastic import (ElasticPlan, StragglerMonitor,
@@ -99,7 +99,8 @@ class TestConstraint:
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
     def test_applies_inside_mesh(self):
-        mesh = shd.make_mesh_compat((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
 
         @jax.jit
         def f(x):
@@ -110,7 +111,8 @@ class TestConstraint:
         assert out.shape == (4, 4)
 
     def test_drops_indivisible(self):
-        mesh = shd.make_mesh_compat((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
 
         @jax.jit
         def f(x):
@@ -185,7 +187,7 @@ class TestSketchedReduce:
         from repro.core import sketch as cs
         from repro.distributed import sketched_reduce as sr
         from jax.sharding import PartitionSpec as P
-        mesh = shd.make_mesh_compat((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         spec = cs.for_param((128, 8), compression=4.0, width_multiple=8)
         ids = jnp.arange(16, dtype=jnp.int32)
         rows = jnp.ones((16, 8), jnp.float32)
@@ -193,7 +195,7 @@ class TestSketchedReduce:
         def f(ids, rows):
             return sr.reduce_gradient_sketch(spec, ids, rows, "data")
 
-        out = jax.jit(shd.shard_map_compat(
+        out = jax.jit(jax.shard_map(
             f, mesh=mesh, in_specs=(P(), P()), out_specs=P()))(ids, rows)
         want = sr.local_sketch(spec, ids, rows)
         np.testing.assert_allclose(np.asarray(out), np.asarray(want),
@@ -518,7 +520,8 @@ class TestOptStateSharding:
         from repro.train.steps import make_train_step
         cfg = configs.get("qwen2_0_5b").reduced()
         ts = make_train_step(cfg, optimizer="cs_adam")
-        mesh = shd.make_mesh_compat((1, 1), ("data", "model"))
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         pshard, oshard, bshard, mshard = ts.shardings(mesh, {})
         os_ = ts.opt_shape()
         flat_o, _ = jax.tree_util.tree_flatten_with_path(

@@ -22,6 +22,7 @@ import subprocess
 import sys
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -42,7 +43,8 @@ N, D, B = 512, 16, 128          # table rows, dim, global batch
 
 
 def _mesh():
-    return shd.make_mesh_compat((N_DEV, 1), ("data", "model"))
+    return jax.make_mesh((N_DEV, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def _batch(seed):

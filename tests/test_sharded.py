@@ -21,6 +21,7 @@ import subprocess
 import sys
 
 import jax
+from jax.sharding import AxisType
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -139,16 +140,17 @@ def _steps(layout, *, dp=False, track_m=True, feedback=False):
     kw = dict(lr=1e-2, b1=0.5, b2=0.5, hparams=hp, track_first_moment=track_m)
     if dp:
         shards = 4
-        mesh = shd.make_mesh_compat((N_DEV // shards, shards),
-                                    ("data", "model"))
-        ref_mesh = shd.make_mesh_compat((N_DEV // shards,), ("data",))
+        mesh = jax.make_mesh((N_DEV // shards, shards), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
+        ref_mesh = jax.make_mesh((N_DEV // shards,), ("data",),
+                                 axis_types=(AxisType.Auto,))
     else:
         shards = N_DEV
-        mesh = shd.make_mesh_compat((N_DEV,), ("model",))
+        mesh = jax.make_mesh((N_DEV,), ("model",), axis_types=(AxisType.Auto,))
         # the sharded step applies the same dir_clip trust clamp as the
         # dp path, so the bit-parity reference is the dp step at dp=1,
         # not the clamp-less single-device step
-        ref_mesh = shd.make_mesh_compat((1,), ("data",))
+        ref_mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     init_fn, sh_step, sh_opt = make_sparse_embedding_step(
         N, D, dp_axis="data" if dp else None, mesh=mesh,
         sketch_shards=shards, shard_layout=layout,
@@ -207,7 +209,8 @@ class TestShardedParityGrid:
     @multidevice
     def test_sharded_state_is_placed_on_the_shard_axis(self):
         init_fn, (sh_step, sh_opt), _ = _steps("width", dp=True)
-        mesh = shd.make_mesh_compat((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         state = jax.device_put(
             sh_opt.init(),
             shd.named(mesh, shd.sketch_state_specs(
@@ -231,7 +234,8 @@ def _sharded_tree(shards=1, layout="width", width=64):
 
 class TestOptSpecsShardedClassification:
     def _mesh2d(self):
-        return shd.make_mesh_compat((1, 1), ("data", "model"))
+        return jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
 
     def _state(self, chain_prefix="0/", residual=False):
         st = {"step": jnp.zeros(()),
@@ -265,7 +269,7 @@ class TestOptSpecsShardedClassification:
     def test_strict_raises_on_sharded_store_without_shard_axis(self):
         # a mesh with NO 'model' axis cannot place 4-shard sketch state;
         # strict must refuse to silently replicate it
-        mesh = shd.make_mesh_compat((1,), ("data",))
+        mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
         params = {"emb": {"table": jnp.zeros((N, D))}}
         with pytest.raises(ValueError, match="refusing to silently"):
             shd.opt_specs_for_state(
