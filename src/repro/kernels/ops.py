@@ -163,7 +163,8 @@ def adam_rows_xla(spec_m: Optional[SketchSpec], spec_v: SketchSpec,
         V = cs.update(spec_v, V, uids, dv, sr_seed=sr_v)
         vhat = jnp.maximum(v_old + dv, 0.0) / bc2
         upd = mask * (-eta) * mhat / (jnp.sqrt(vhat) + eps)
-    return M, V, dd.scatter_back(batch, upd)
+    with jax.named_scope("obs.apply"):
+        return M, V, dd.scatter_back(batch, upd)
 
 
 def adam_rows_tiled(spec_m: Optional[SketchSpec], spec_v: SketchSpec,
@@ -193,7 +194,8 @@ def adam_rows_tiled(spec_m: Optional[SketchSpec], spec_v: SketchSpec,
             M, V, bm, sm, bv, batch.rows, lr=eta, b1=b1, b2=b2, eps=eps,
             bc1=bc1, bc2=bc2, n_valid=batch.n_unique, tile=tile,
             interpret=interpret)
-    return M_out, V_out, dd.scatter_back(batch, upd_u)
+    with jax.named_scope("obs.apply"):
+        return M_out, V_out, dd.scatter_back(batch, upd_u)
 
 
 # ---------------------------------------------------------------------------

@@ -11,8 +11,10 @@ assuming it (DESIGN.md §15):
     sampled hot/cold rows vs sketch ``read()`` estimates), per-store
     health stats via ``AuxStore.stats``, planner predicted-vs-measured
     collision error, and the ``RunObserver`` the Trainer drives;
-  * ``profiling`` — named ``jax.profiler.TraceAnnotation`` phase spans,
-    ``--profile-dir`` trace dumps, and p50/p99 latency histograms;
+  * ``profiling`` — ``train.*`` host spans (``TraceAnnotation`` plus a
+    ``PhaseTimer``), the ``obs.*`` scope of each compiled instruction,
+    a compile counter, ``--profile-dir`` trace dumps, and p50/p99
+    latency histograms;
   * ``report``    — ``python -m repro.obs.report``: render a run's JSONL
     into a health summary with re-planning warnings.
 """
@@ -20,12 +22,13 @@ from repro.obs.metrics import (MetricsWriter, SCHEMA_VERSION, StepAccumulator,
                                validate_file, validate_record)
 from repro.obs.probes import (RunObserver, TableMonitor, TableProbe,
                               predicted_table_errors, rows_ema_update)
-from repro.obs.profiling import (LatencyTracker, PhaseTimer, maybe_trace,
-                                 scope)
+from repro.obs.profiling import (CompileCounter, LatencyTracker, PhaseTimer,
+                                 maybe_trace, scope, scope_map, span)
 
 __all__ = [
     "MetricsWriter", "SCHEMA_VERSION", "StepAccumulator", "validate_file",
     "validate_record", "RunObserver", "TableMonitor", "TableProbe",
-    "predicted_table_errors", "rows_ema_update", "LatencyTracker",
-    "PhaseTimer", "maybe_trace", "scope",
+    "predicted_table_errors", "rows_ema_update", "CompileCounter",
+    "LatencyTracker", "PhaseTimer", "maybe_trace", "scope", "scope_map",
+    "span",
 ]

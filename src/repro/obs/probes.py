@@ -421,13 +421,6 @@ class RunObserver:
         self._window: List[Dict[str, float]] = []
         self._emitted_at: Optional[int] = None
 
-    def phase(self, name: str):
-        """Host-side span (no-op without a phase timer)."""
-        if self.phase_timer is None:
-            import contextlib
-            return contextlib.nullcontext()
-        return self.phase_timer.phase(name)
-
     def on_step(self, step: int, rec: Dict[str, float],
                 opt_state=None) -> None:
         self._window.append(rec)

@@ -1,18 +1,23 @@
 """Phase-level profiling: named spans, trace dumps, latency histograms.
 
-Two complementary span mechanisms (DESIGN.md §15):
+Two span mechanisms (DESIGN.md §15), both readable from a chip trace:
 
   * ``scope(name)`` — ``jax.named_scope`` for code INSIDE a jit trace
-    (dedup / kernel / clean / collective).  Free at runtime; the names
-    survive into HLO and show up in ``--profile-dir`` traces.
-  * ``PhaseTimer.phase(name)`` — host-side spans around the training
-    loop's phases (data / step / checkpoint).  Each span enters a
-    ``jax.profiler.TraceAnnotation`` (so it lines up with device traces)
-    AND accumulates wall time, drained into ``phase`` metrics records.
+    (``obs.dedup`` / ``obs.kernel`` / ``obs.apply`` / ``obs.clean`` /
+    ``obs.collective``).  Free at runtime.  A TPU trace's op events carry
+    only the HLO instruction's text, so a scope reaches the trace through
+    the compiled module's metadata: ``scope_map`` maps each instruction
+    of ``compiled.as_text()`` to its innermost ``obs.*`` scope.
+  * ``span(name, timer)`` — a host-side span around one phase of the
+    training loop (``train.data`` / ``train.feed`` / ``train.clean`` /
+    ``train.dispatch`` / ``train.wait`` / ``train.record`` /
+    ``train.checkpoint``).  It enters a ``jax.profiler.TraceAnnotation``
+    (the trace's clock) AND adds its wall time to a ``PhaseTimer``,
+    drained into ``phase`` metrics records.
 
-Span naming convention: dotted ``obs.<phase>`` names — ``obs.dedup``,
-``obs.kernel``, ``obs.clean``, ``obs.collective`` inside the step;
-``data`` / ``step`` / ``checkpoint`` at the loop level.
+``CompileCounter`` counts the programs the backend compiles (or loads
+from the persistent cache) while it is open: a compile inside a timed
+window is a stall of its own.
 
 ``LatencyTracker`` is the p50/p99 machinery behind serve-side adapt
 latency and trainer steps/s histograms: a bounded ring buffer of
@@ -22,10 +27,19 @@ durations summarized into the schema's histogram shape
 from __future__ import annotations
 
 import contextlib
+import re
 import time
 from typing import Dict, Iterator, Optional
 
 import numpy as np
+
+UNSCOPED = "unscoped"
+# ``jax._src.dispatch.BACKEND_COMPILE_EVENT``: wraps every backend compile,
+# a persistent-cache hit included
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+_INSTRUCTION = re.compile(r"^\s*(?:ROOT\s+)?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
 
 
 def scope(name: str):
@@ -34,31 +48,42 @@ def scope(name: str):
     return jax.named_scope(name)
 
 
+def scope_map(hlo_text: str) -> Dict[str, str]:
+    """``{instruction: scope}`` over an HLO module's text (the optimized
+    module of ``compiled.as_text()``, whose instruction names are those the
+    trace's op events carry): each instruction's innermost ``obs.*``
+    component of its ``metadata={op_name=...}`` path, or ``UNSCOPED``."""
+    out = {}
+    for line in hlo_text.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        op = _OP_NAME.search(line)
+        path = op.group(1).split("/") if op else ()
+        out[m.group(1)] = next((c for c in reversed(path)
+                                if c.startswith("obs.")), UNSCOPED)
+    return out
+
+
 class PhaseTimer:
-    """Host-side named phase spans with wall-time accumulation.
+    """Wall time of host-side phases, per phase name.
 
         timer = PhaseTimer()
-        with timer.phase("data"):
+        with span("train.data", timer):
             batch = stream.batch(i)
         ...
-        record = timer.drain()   # {"data": {count, total_ms, mean_ms}, ...}
+        record = timer.drain()   # {"train.data": {count, total_ms, ...}}
     """
 
     def __init__(self):
         self._total_s: Dict[str, float] = {}
+        self._max_s: Dict[str, float] = {}
         self._count: Dict[str, int] = {}
 
-    @contextlib.contextmanager
-    def phase(self, name: str) -> Iterator[None]:
-        import jax
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation(name):
-            try:
-                yield
-            finally:
-                dt = time.perf_counter() - t0
-                self._total_s[name] = self._total_s.get(name, 0.0) + dt
-                self._count[name] = self._count.get(name, 0) + 1
+    def add(self, name: str, seconds: float) -> None:
+        self._total_s[name] = self._total_s.get(name, 0.0) + seconds
+        self._max_s[name] = max(self._max_s.get(name, 0.0), seconds)
+        self._count[name] = self._count.get(name, 0) + 1
 
     def drain(self) -> Dict[str, Dict[str, float]]:
         """Per-phase timing since the last drain; resets the counters."""
@@ -67,10 +92,52 @@ class PhaseTimer:
             n = self._count[name]
             out[name] = {"count": n,
                          "total_ms": round(total * 1e3, 4),
-                         "mean_ms": round(total * 1e3 / max(n, 1), 4)}
+                         "mean_ms": round(total * 1e3 / max(n, 1), 4),
+                         "max_ms": round(self._max_s[name] * 1e3, 4)}
         self._total_s.clear()
+        self._max_s.clear()
         self._count.clear()
         return out
+
+
+@contextlib.contextmanager
+def span(name: str, timer: PhaseTimer) -> Iterator[None]:
+    """A host span: a ``TraceAnnotation`` named ``name`` whose wall time
+    ``timer`` also records."""
+    import jax
+    t0 = time.perf_counter()
+    try:
+        with jax.profiler.TraceAnnotation(name):
+            yield
+    finally:
+        timer.add(name, time.perf_counter() - t0)
+
+
+class CompileCounter:
+    """Backend compiles (persistent-cache loads included) while open.
+
+        with CompileCounter() as compiles:
+            trainer.fit(state)
+        compiles.count, compiles.seconds
+    """
+
+    def __init__(self):
+        self.count = 0
+        self.seconds = 0.0
+
+    def _on_event(self, event: str, duration_secs: float, **_) -> None:
+        if event == BACKEND_COMPILE_EVENT:
+            self.count += 1
+            self.seconds += duration_secs
+
+    def __enter__(self) -> "CompileCounter":
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+        return self
+
+    def __exit__(self, *exc) -> None:
+        import jax
+        jax.monitoring.unregister_event_duration_listener(self._on_event)
 
 
 class LatencyTracker:

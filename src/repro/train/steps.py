@@ -367,7 +367,9 @@ def make_sparse_embedding_step(n_rows: int, dim: int, *, lr=1e-3,
         with scope("obs.kernel"):
             updates, opt_state = opt.update(
                 {"ids": ids, "rows": grad_rows}, opt_state)
-        return opt_lib.apply_sparse_updates(table, updates), opt_state
+        with scope("obs.apply"):
+            table = opt_lib.apply_sparse_updates(table, updates)
+        return table, opt_state
 
     if sketch_shards > 1:
         wrapped = shd.sharded_sparse_wrap(local_step, mesh=mesh,

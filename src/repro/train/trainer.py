@@ -1,7 +1,7 @@
 """Fault-tolerant training loop.
 
 Composes the pieces the launcher needs: jit'd step, deterministic data,
-atomic/async checkpoints, straggler monitoring, and crash recovery (via
+atomic/async checkpoints, per-phase host spans, and crash recovery (via
 ``repro.distributed.elastic.recovery_loop``).  The loop is synchronous
 SPMD (JAX semantics); fault tolerance is checkpoint/restart with the
 deterministic pipeline replaying the exact stream — resumed runs are
@@ -17,7 +17,7 @@ import jax
 import numpy as np
 
 from repro.checkpoint import store
-from repro.distributed.elastic import StragglerMonitor
+from repro.obs.profiling import PhaseTimer, span
 
 
 @dataclasses.dataclass
@@ -28,7 +28,6 @@ class TrainerConfig:
     ckpt_async: bool = True
     keep: int = 3
     log_every: int = 10
-    host_id: int = 0
 
 
 @dataclasses.dataclass
@@ -40,6 +39,14 @@ class TrainState:
 
 class Trainer:
     """``fit`` runs [start, total); checkpoints; records step times.
+
+    Every phase of a step runs in a ``profiling.span``: ``train.data``
+    (``data.batch``), ``train.feed`` (the host-to-device copy),
+    ``train.clean`` (with a cleaner), ``train.dispatch`` (the ``step_fn``
+    call), ``train.wait`` (until the loss is ready), ``train.record``
+    (the metric fetches, history and observer) and ``train.checkpoint``.
+    Their wall times go to ``phases``: the observer's ``phase_timer`` when
+    it has one, the trainer's own ``PhaseTimer`` otherwise.
 
     ``plan``: an optional ``repro.plan.Plan`` executing on this run (in
     place of a bare sketch policy).  Both the plan and its executable
@@ -56,13 +63,11 @@ class Trainer:
     heuristic (``repro.distributed.elastic.elastic_restore``)."""
 
     def __init__(self, step_fn: Callable, data, tcfg: TrainerConfig,
-                 monitor: Optional[StragglerMonitor] = None,
                  fail_at: Optional[int] = None, plan=None,
                  store_tree=None, observer=None, cleaner=None):
         self.step_fn = step_fn
         self.data = data
         self.tcfg = tcfg
-        self.monitor = monitor or StragglerMonitor()
         self.history: List[Dict[str, float]] = []
         self.plan = plan
         self.store_tree = store_tree
@@ -72,11 +77,12 @@ class Trainer:
         # successful completion (a crash-restart re-enters fit with the
         # observer still open, so no partial window is lost)
         self.observer = observer
+        self.phases = getattr(observer, "phase_timer", None) or PhaseTimer()
         # optional repro.core.cleaning.AsyncCleaner: dispatches the §4
         # count-min decay BETWEEN steps (mode='async'), at the same
         # boundary the sync lax.cond keys on, so numerics stay
         # bit-identical while the decay's cost moves off the step
-        # phase's critical section into its own 'clean' phase span
+        # phase's critical section into its own 'train.clean' span
         self.cleaner = cleaner
         if plan is not None and store_tree is not None \
                 and plan.store_tree() != store_tree:
@@ -121,23 +127,19 @@ class Trainer:
         return TrainState(step=step, params=tree["params"],
                           opt_state=tree["opt_state"])
 
-    def _obs_phase(self, name: str):
-        if self.observer is None:
-            import contextlib
-            return contextlib.nullcontext()
-        return self.observer.phase(name)
-
     def fit(self, state: TrainState) -> TrainState:
         t = self.tcfg
+        timer = self.phases
         while state.step < t.total_steps:
             if self._fail_at is not None and state.step == self._fail_at:
                 self._fail_at = None          # fail once
                 raise RuntimeError(f"injected failure at step {state.step}")
-            with self._obs_phase("data"):
+            with span("train.data", timer):
                 batch = self.data.batch(state.step)
+            with span("train.feed", timer):
                 batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
             if self.cleaner is not None:
-                with self._obs_phase("clean"):
+                with span("train.clean", timer):
                     # the upcoming step observes counter state.step + 1 —
                     # the boundary the sync schedule's in-step lax.cond
                     # keys on; dispatch is non-blocking (device dataflow
@@ -148,22 +150,24 @@ class Trainer:
                                        params=state.params,
                                        opt_state=opt_state)
             t0 = time.perf_counter()
-            with self._obs_phase("step"):
+            with span("train.dispatch", timer):
                 params, opt_state, metrics = self.step_fn(
                     state.params, state.opt_state, batch)
+            with span("train.wait", timer):
                 jax.block_until_ready(metrics["loss"])
             dt = time.perf_counter() - t0
-            self.monitor.record(t.host_id, dt)
             state = TrainState(step=state.step + 1, params=params,
                                opt_state=opt_state)
-            rec = {"step": state.step, "time_s": dt,
-                   **{k: float(np.asarray(v)) for k, v in metrics.items()}}
-            self.history.append(rec)
-            if self.observer is not None:
-                self.observer.on_step(state.step, rec, state.opt_state)
-            with self._obs_phase("checkpoint"):
+            with span("train.record", timer):
+                rec = {"step": state.step, "time_s": dt,
+                       **{k: float(np.asarray(v))
+                          for k, v in metrics.items()}}
+                self.history.append(rec)
+                if self.observer is not None:
+                    self.observer.on_step(state.step, rec, state.opt_state)
+            with span("train.checkpoint", timer):
                 self._maybe_checkpoint(state)
-        with self._obs_phase("checkpoint"):
+        with span("train.checkpoint", timer):
             self._maybe_checkpoint(state, force=True)
             if self._pending_ckpt is not None:
                 self._pending_ckpt.join()

@@ -24,7 +24,8 @@ from repro.obs.metrics import (REQUIRED_FIELDS, SCHEMA_VERSION, MetricsWriter,
 from repro.obs.probes import (RunObserver, TableMonitor, TableProbe,
                               predicted_table_errors, probe_row_ids,
                               rows_ema_update)
-from repro.obs.profiling import LatencyTracker, PhaseTimer
+from repro.obs import profiling
+from repro.obs.profiling import LatencyTracker, PhaseTimer, span
 from repro.obs.report import analyze
 from repro.plan.error_model import TableStats, countmin_error
 
@@ -185,7 +186,7 @@ class TestObserverEndToEnd:
               "probe": probe.init(self.D)}
         for i, (ids, rows) in enumerate(
                 _stream(self.N, self.D, steps, 32), start=1):
-            with obs.phase("step"):
+            with span("step", obs.phase_timer):
                 st["m"] = rows_ema_update(m_store, st["m"], ids, rows,
                                           probe.b1)
                 st["v"] = rows_ema_update(v_store, st["v"], ids, rows,
@@ -388,3 +389,111 @@ class TestQuantNoiseGauge:
             "t", (self.N, self.D), jnp.float32)
         errs = self._drive(store)
         assert "v_quant_noise" not in errs
+
+
+class TestProfiling:
+    """Host spans, the scope map of compiled instructions, the phase timer
+    and the compile counter (``obs/profiling.py``)."""
+
+    def test_scope_map_innermost_wins(self):
+        text = "\n".join([
+            "HloModule m, entry_computation_layout={(f32[4])->f32[4]}",
+            "ENTRY %main (p: f32[4]) -> f32[4] {",
+            "  %p = f32[4]{0} parameter(0)",
+            '  %a.1 = f32[4]{0} sine(%p), metadata={op_name="jit(f)/'
+            'obs.kernel/sin" source_file="f.py" source_line=3}',
+            '  %b = f32[4]{0} cosine(%a.1), metadata={op_name="jit(f)/cos"}',
+            '  ROOT %sort.2 = f32[4]{0} sort(%b), metadata={op_name="jit(f)/'
+            'obs.kernel/obs.dedup/jit(argsort)/sort"}',
+            "}"])
+        assert profiling.scope_map(text) == {
+            "p": "unscoped", "a.1": "obs.kernel", "b": "unscoped",
+            "sort.2": "obs.dedup"}
+
+    def test_scope_map_of_compiled_function(self):
+        def f(x):
+            with profiling.scope("obs.kernel"):
+                y = jnp.sin(x) * 3.0
+                with profiling.scope("obs.dedup"):
+                    z = jnp.sort(y)
+            return z + jnp.cos(x).sum()
+
+        text = jax.jit(f).lower(jnp.ones((64,))).compile().as_text()
+        m = profiling.scope_map(text)
+        sorts = [ln.split(" = ")[0].split("%")[-1]
+                 for ln in text.splitlines() if " = " in ln and " sort(" in ln]
+        assert sorts and all(m[k] == "obs.dedup" for k in sorts)
+        assert {"obs.dedup", "unscoped"} <= set(m.values())
+        assert set(m.values()) <= {"obs.kernel", "obs.dedup", "unscoped"}
+
+    def test_phase_timer_drain_max_ms(self):
+        t = PhaseTimer()
+        for s in (0.001, 0.003, 0.002):
+            t.add("train.wait", s)
+        t.add("train.data", 0.0005)
+        out = t.drain()
+        assert out["train.wait"] == {"count": 3, "total_ms": 6.0,
+                                     "mean_ms": 2.0, "max_ms": 3.0}
+        assert out["train.data"]["max_ms"] == 0.5
+        assert t.drain() == {}
+
+    def test_span_records_on_error(self):
+        t = PhaseTimer()
+        with pytest.raises(ValueError):
+            with span("train.data", t):
+                raise ValueError("boom")
+        assert t.drain()["train.data"]["count"] == 1
+
+    def test_compile_counter(self):
+        f = jax.jit(lambda x: x * 1.2345 + 0.5)
+        x = jnp.ones((7, 3))
+        with profiling.CompileCounter() as c:
+            f(x).block_until_ready()
+            f(x).block_until_ready()          # cached: no new compile
+        assert c.count == 1 and c.seconds > 0
+        jax.jit(lambda x: x - 0.25)(x).block_until_ready()
+        assert c.count == 1                   # closed: listens no more
+
+    def test_trainer_opens_train_spans_in_order(self, tmp_path):
+        from jax.profiler import ProfileData
+
+        from repro.train.trainer import Trainer, TrainerConfig, TrainState
+
+        class Data:
+            def batch(self, step):
+                return {"x": np.full((4,), step, np.float32)}
+
+        class Cleaner:
+            def maybe_dispatch(self, opt_state, step):
+                return opt_state, False
+
+        def step_fn(p, s, batch):
+            p = p + batch["x"].mean()
+            return p, s + 1, {"loss": jnp.sum(p)}
+
+        tr = Trainer(jax.jit(step_fn), Data(), TrainerConfig(total_steps=2),
+                     cleaner=Cleaner())
+        state = TrainState(step=0, params=jnp.zeros(()),
+                           opt_state=jnp.zeros((), jnp.int32))
+        tr.fit(state)                         # compile outside the trace
+        tr.phases.drain()
+        tr.tcfg.total_steps = 4
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            tr.fit(TrainState(2, state.params, state.opt_state))
+        finally:
+            jax.profiler.stop_trace()
+        path = next(tmp_path.rglob("*.xplane.pb"))
+        events = sorted(
+            (ev.start_ns, ev.name)
+            for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:CPU")
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith("train."))
+        step = ["train.data", "train.feed", "train.clean", "train.dispatch",
+                "train.wait", "train.record", "train.checkpoint"]
+        assert [n for _, n in events] == step + step + ["train.checkpoint"]
+        phases = tr.phases.drain()
+        assert sorted(phases) == sorted(step)
+        assert phases["train.checkpoint"]["count"] == 3
+        assert all(phases[n]["count"] == 2 for n in step[:-1])
