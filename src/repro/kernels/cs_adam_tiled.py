@@ -34,7 +34,9 @@ batches longer than one call's SMEM address budget run as consecutive
 calls with the same streaming order.
 
 Rows past ``n_valid`` (dedup/tile padding) contribute exactly zero to
-every sketch bucket and emit zero update rows.
+every sketch bucket and emit zero update rows.  A tile that starts at or
+past ``n_valid`` moves no sketch group and fetches no new gradient
+block: it only writes its zero update rows.
 
 Oracle: ``ref.adam_fused_ref`` on collision-free batches (exact);
 ``tests/test_backends.py`` quantifies the colliding-batch tolerance.
@@ -61,8 +63,22 @@ def _tiled_kernel(depth: int, tile: int, track_m: bool,
                   M_any, V_any,                     # sketches, pl.ANY (HBM)
                   M_out, V_out, upd_out,            # aliased outs + updates
                   m_stage, v_stage, sem):           # group VMEM + DMA sem
-    t = pl.program_id(0)
-    base = t * tile
+    base = pl.program_id(0) * tile
+
+    @pl.when(base < nv_ref[0])
+    def _():
+        _live_tile(depth, tile, track_m, base, bm_ref, sm_ref, bv_ref,
+                   nv_ref, hyper, g_blk, M_out, V_out, upd_out, m_stage,
+                   v_stage, sem)
+
+    @pl.when(base >= nv_ref[0])
+    def _():
+        upd_out[:, :] = jnp.zeros(upd_out.shape, upd_out.dtype)
+
+
+def _live_tile(depth, tile, track_m, base, bm_ref, sm_ref, bv_ref, nv_ref,
+               hyper, g_blk, M_out, V_out, upd_out, m_stage, v_stage, sem):
+    """One tile that holds live rows: DMA its groups in, update, DMA back."""
     g = rg.group_rows(jnp.float32)
     lr, b1, b2, eps, bc1, bc2 = (hyper[0], hyper[1], hyper[2], hyper[3],
                                  hyper[4], hyper[5])
@@ -129,12 +145,17 @@ def _one_call(M, V, bm, sm, bv, nv, g, hyper, *, tile, track_m, interpret):
     depth, w, d = V.shape
     k = g.shape[0]
     rows = tile * rg.group_rows(jnp.float32)
+
+    def grad_tile(t, bm, sm, bv, nv):
+        # a dead tile keeps the last live tile's block: nothing refetched
+        return jnp.minimum(t, jnp.maximum(nv[0] - 1, 0) // tile), 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,      # bm, sm, bv, n_valid
         grid=(k // tile,),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),          # hyper
-            pl.BlockSpec((tile, d), lambda t, *_: (t, 0)),  # grad tile
+            pl.BlockSpec((tile, d), grad_tile),             # grad tile
             pl.BlockSpec(memory_space=pl.ANY),              # M (HBM)
             pl.BlockSpec(memory_space=pl.ANY),              # V (HBM)
         ],

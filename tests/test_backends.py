@@ -331,3 +331,61 @@ def test_tiled_split_into_calls_matches_one_call(kernel, monkeypatch):
     split = run()
     for a, c in zip(whole, split):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
+
+
+@jax.jit
+def _adam_tiled_calls(M, V, b, s, g, n_valid):
+    """``cs_adam_tiled`` in interpret mode, ``n_valid`` traced as the
+    sparse step passes it."""
+    from repro.kernels.cs_adam_tiled import cs_adam_tiled
+    return cs_adam_tiled(M, V, b, s, b[::-1], g, lr=1e-2, b1=0.9, b2=0.999,
+                         eps=1e-8, bc1=0.19, bc2=0.002, n_valid=n_valid,
+                         interpret=True)
+
+
+# k = 48 rows in calls of 16: no row, one, a partial tile, one tile, a
+# call boundary and one past it, a ragged last call, every row
+@pytest.mark.parametrize("n_valid", [0, 1, 7, 8, 16, 17, 43, 48])
+def test_tiled_adam_skips_rows_past_n_valid(n_valid, monkeypatch):
+    """Rows past ``n_valid`` do no work, over several calls: with every one
+    of them redrawn (other buckets, signs and gradients) M, V and the live
+    update rows are bit-identical, every update row past ``n_valid`` reads
+    0 (+0.0 past the live tiles), and with no live row M and V come back
+    unchanged."""
+    from repro.kernels import row_groups as rg
+    depth, width, d, k, tile = 3, 16, 128, 48, 8
+    rng = np.random.RandomState(11)
+    M = jnp.asarray(rng.randn(depth, width, d), jnp.float32)
+    V = jnp.abs(jnp.asarray(rng.randn(depth, width, d), jnp.float32))
+    b = rng.randint(0, width, (depth, k))
+    s = rng.choice([-1.0, 1.0], (depth, k))
+    g = rng.randn(k, d)
+    b2, s2, g2 = b.copy(), s.copy(), g.copy()
+    b2[:, n_valid:] = rng.randint(0, width, (depth, k - n_valid))
+    s2[:, n_valid:] *= -1
+    g2[n_valid:] = 1e3 * rng.randn(k - n_valid, d)
+    live = -(-n_valid // tile) * tile
+    nv = jnp.int32(n_valid)
+    monkeypatch.setattr(rg, "_SMEM_BUDGET", 3 * 8 * 4 * 16)
+    assert rg.split_calls(k, rg.rows_per_call(depth, 3, tile), tile) \
+        == (16, 3)
+
+    def run(b, s, g):
+        return _adam_tiled_calls(M, V, jnp.asarray(b, jnp.int32),
+                                 jnp.asarray(s, jnp.float32),
+                                 jnp.asarray(g, jnp.float32), nv)
+
+    def bits(a):
+        return np.asarray(a).view(np.uint32)
+
+    got, alt = run(b, s, g), run(b2, s2, g2)
+    np.testing.assert_array_equal(bits(got[0]), bits(alt[0]))
+    np.testing.assert_array_equal(bits(got[1]), bits(alt[1]))
+    np.testing.assert_array_equal(bits(got[2][:n_valid]),
+                                  bits(alt[2][:n_valid]))
+    for out in (got, alt):
+        assert np.all(np.asarray(out[2][n_valid:]) == 0)
+        assert np.all(bits(out[2][live:]) == 0)
+    if n_valid == 0:
+        np.testing.assert_array_equal(bits(got[0]), bits(M))
+        np.testing.assert_array_equal(bits(got[1]), bits(V))

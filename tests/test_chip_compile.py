@@ -74,6 +74,30 @@ def test_cs_adam_tiled_compiles(one_chip, depth, width, dim, k):
     assert _kernels_in(compiled) >= 1
 
 
+@pytest.mark.parametrize("k", [32768, 442368])
+def test_cs_adam_tiled_compiles_with_live_bound(one_chip, k):
+    """``n_valid`` traced and below ``k``, over several calls (7 at
+    32,768 rows, 82 at cat_21's 442,368): the kernel's guard around each
+    tile's work and its clamped gradient block, inside the loop of calls."""
+    f32, i32 = jnp.float32, jnp.int32
+    depth, width, dim = 3, 666880, 128
+
+    def step(M, V, bm, sm, bv, g, n_valid):
+        return cs_adam_tiled(M, V, bm, sm, bv, g, lr=1e-3, b1=0.9,
+                             b2=0.999, eps=1e-8, bc1=0.1, bc2=0.001,
+                             n_valid=n_valid)
+
+    compiled = jax.jit(step).lower(
+        _sds((depth, width, dim), f32, one_chip),
+        _sds((depth, width, dim), f32, one_chip),
+        _sds((depth, k), i32, one_chip), _sds((depth, k), f32, one_chip),
+        _sds((depth, k), i32, one_chip), _sds((k, dim), f32, one_chip),
+        _sds((), i32, one_chip)).compile()
+    text = compiled.as_text()
+    assert _kernels_in(compiled) >= 1
+    assert " while(" in text
+
+
 EMA_SHAPES = [(3, 16384, 128, 1024, "float32", True),
               (3, 16384, 128, 1024, "float32", False),
               (3, 16384, 128, 1024, "bfloat16", True),
@@ -121,12 +145,9 @@ def test_qwen2_vocab_dense_path_compiles(one_chip, signed):
     assert _kernels_in(compiled) >= 1
 
 
-def test_sparse_step_compiles_with_tiled(one_chip):
-    """The jitted sparse-embedding step on a 1,048,576 x 128 table with a
-    65,536-id batch.  ``auto`` resolves by the host this test runs on (a
-    CPU), so the test names 'tiled' — what ``auto`` picks on a TPU."""
+def _compile_sparse_step(one_chip, k):
     from repro.train.steps import make_sparse_embedding_step
-    n, dim, k = 1 << 20, 128, 65536
+    n, dim = 1 << 20, 128
     _, step_fn, opt = make_sparse_embedding_step(
         n, dim, hparams=SketchHParams(compression=5.0, backend="tiled"))
     state = jax.eval_shape(opt.init)
@@ -139,6 +160,19 @@ def test_sparse_step_compiles_with_tiled(one_chip):
             _sds((k, dim), jnp.float32, one_chip))
     assert {r[2] for r in rec} == {"tiled"}
     assert _kernels_in(lowered.compile()) >= 1
+
+
+def test_sparse_step_compiles_with_tiled(one_chip):
+    """The jitted sparse-embedding step on a 1,048,576 x 128 table with a
+    65,536-id batch.  ``auto`` resolves by the host this test runs on (a
+    CPU), so the test names 'tiled' — what ``auto`` picks on a TPU."""
+    _compile_sparse_step(one_chip, 65536)
+
+
+def test_sparse_step_compiles_at_cat21_ids(one_chip):
+    """The same step with cat_21's 442,368 ids a step: 82 calls of the
+    kernel, a traced number of their tiles live."""
+    _compile_sparse_step(one_chip, 442368)
 
 
 # (dim, cell dtype) -> what 'auto' runs on a TPU for the sparse-rows pair
