@@ -42,13 +42,18 @@ _ALIASES = {
     "zamba2-2.7b": "zamba2_2_7b",
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
     "llama4-maverick-400b-a17b": "llama4_maverick_400b_a17b",
+    # trained at one chip's share only: not in ARCH_IDS' dry-run matrix
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
+    "moonlight-16b-a3b-ep8": "moonlight_16b_a3b:EP8",
 }
 
 
 def get(arch: str) -> ArchConfig:
-    mod_name = _ALIASES.get(arch, arch).replace("-", "_").replace(".", "_")
+    """The config named ``arch``; an alias may name ``module:ATTR``."""
+    name, _, attr = _ALIASES.get(arch, arch).partition(":")
+    mod_name = name.replace("-", "_").replace(".", "_")
     mod = importlib.import_module(f"repro.configs.{mod_name}")
-    return mod.CONFIG
+    return getattr(mod, attr or "CONFIG")
 
 
 def registry() -> Dict[str, ArchConfig]:

@@ -3,6 +3,10 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2_0_5b \
         --reduced --steps 200 --optimizer cs_adam --ckpt-dir /tmp/run1
 
+    # one chip's share of Moonlight-16B-A3B over 8-way expert parallelism
+    python -m repro.launch.train --arch moonlight-16b-a3b-ep8 \
+        --batch 2 --seq 8192 --steps 20
+
 On a real pod this binary runs per host (jax.distributed.initialize is
 called when JAX_COORDINATOR is set); here it exercises the same code
 path on one CPU device.  ``--reduced`` swaps in the smoke-size config.
@@ -93,6 +97,18 @@ def compile_step(jit_step, *args):
               f"{mem.temp_size_in_bytes:,} B", flush=True)
     return compiled, RunReport(rc=1, compile_s=dt, backends=backends,
                                memory=mem)
+
+
+def jit_train_step(ts, mesh, batch_tpl, step_fn=None):
+    """``jax.jit`` of a ``TrainStep``'s step (or of ``step_fn``, a wrapper
+    of it taking batches shaped as ``batch_tpl``) with the derived in/out
+    shardings and the params and optimizer state donated.  Returns it
+    with the (params, optimizer state) shardings."""
+    pshard, oshard, bshard, mshard = ts.shardings(mesh, batch_tpl)
+    return jax.jit(step_fn or ts.step_fn,
+                   in_shardings=(pshard, oshard, bshard),
+                   out_shardings=(pshard, oshard, mshard),
+                   donate_argnums=(0, 1)), (pshard, oshard)
 
 
 def make_observer(args, run_meta, monitors=(), subdir: str = ""):
@@ -751,9 +767,13 @@ def run(argv: Optional[Sequence[str]] = None) -> RunReport:
             f"compiles for a TPU only; this host is "
             f"{jax.default_backend()} — resume with --store-backend auto "
             f"(or xla)")
+    # a sparse-embedding LM (``cfg.sparse_embedding``, e.g. the
+    # moonlight-16b-a3b-ep8 share) takes no global-norm clip: its
+    # embedding gradient leaves the tree as rows
     ts = make_train_step(cfg, optimizer=args.optimizer, lr=args.lr,
                          plan=plan, dp_axis="data" if args.dp else None,
-                         kernel_backend=args.store_backend or None)
+                         kernel_backend=args.store_backend or None,
+                         grad_clip=None if cfg.sparse_embedding else 1.0)
 
     with shd.active_mesh(mesh):
         import jax.numpy as jnp
@@ -779,11 +799,7 @@ def run(argv: Optional[Sequence[str]] = None) -> RunReport:
         if cfg.family == "vlm":
             batch_tpl["patches"] = jax.ShapeDtypeStruct(
                 (args.batch, cfg.n_patches, cfg.d_model), cfg.dtype)
-        pshard, oshard, bshard, mshard = ts.shardings(mesh, batch_tpl)
-        step_fn = jax.jit(ts.step_fn,
-                          in_shardings=(pshard, oshard, bshard),
-                          out_shardings=(pshard, oshard, mshard),
-                          donate_argnums=(0, 1))
+        step_fn, (pshard, oshard) = jit_train_step(ts, mesh, batch_tpl)
         tcfg = TrainerConfig(total_steps=args.steps, ckpt_dir=args.ckpt_dir,
                              ckpt_every=args.ckpt_every,
                              log_every=args.log_every)

@@ -14,8 +14,8 @@ reference oracle for tests.  Decode attends one query against the full
 cache (scores are O(seq), no chunking needed).
 
 Layouts:
-  q        (b, s, hq, hd)
-  k, v     (b, s, hkv, hd)         hq % hkv == 0 (GQA groups)
+  q, k     (b, s, hq|hkv, hd)      hq % hkv == 0 (GQA groups)
+  v        (b, s, hkv, dv)         dv == hd, or not (latent attention)
   cache    (b, S_max, hkv, hd)     seq axis shardable over 'model' (SP)
 """
 from __future__ import annotations
@@ -40,10 +40,11 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                       q_offset: int = 0) -> jnp.ndarray:
     """Online-softmax attention over KV chunks.
 
-    q (b,sq,hq,hd); k,v (b,skv,hkv,hd).  ``q_offset``: absolute position of
-    q[0] relative to k[0] (prefill continuation).  Returns (b,sq,hq,hd)."""
+    q, k (b,·,h,hd); v (b,skv,hkv,dv), where ``dv`` may differ from ``hd``
+    (latent attention).  ``q_offset``: absolute position of q[0] relative
+    to k[0] (prefill continuation).  Returns (b,sq,hq,dv)."""
     b, sq, hq, hd = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
+    skv, hkv, dv = k.shape[1], k.shape[2], v.shape[-1]
     g = hq // hkv
     chunk = min(chunk, skv)
     if skv % chunk != 0:
@@ -53,11 +54,11 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     qg = _group(q, hkv).astype(jnp.float32) / jnp.sqrt(hd)  # (b,sq,hkv,g,hd)
     kc = k.reshape(b, n_chunks, chunk, hkv, hd)
-    vc = v.reshape(b, n_chunks, chunk, hkv, hd)
+    vc = v.reshape(b, n_chunks, chunk, hkv, dv)
     q_pos = q_offset + jnp.arange(sq)
 
     def body(carry, xs):
-        m, l, acc = carry                       # (b,hkv,g,sq), ..., (...,hd)
+        m, l, acc = carry                       # (b,hkv,g,sq), ..., (...,dv)
         kb, vb, c_idx = xs                      # (b,chunk,hkv,hd) ×2, ()
         s = jnp.einsum("bqhgd,bchd->bhgqc", qg, kb.astype(jnp.float32))
         if causal:
@@ -74,13 +75,13 @@ def chunked_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
 
     init = (jnp.full((b, hkv, g, sq), NEG_INF, jnp.float32),
             jnp.zeros((b, hkv, g, sq), jnp.float32),
-            jnp.zeros((b, hkv, g, sq, hd), jnp.float32))
+            jnp.zeros((b, hkv, g, sq, dv), jnp.float32))
     (m, l, acc), _ = jax.lax.scan(
         body, init,
         (jnp.moveaxis(kc, 1, 0), jnp.moveaxis(vc, 1, 0),
          jnp.arange(n_chunks)))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]            # (b,hkv,g,sq,hd)
-    out = jnp.moveaxis(out, 3, 1).reshape(b, sq, hq, hd)
+    out = acc / jnp.maximum(l, 1e-30)[..., None]            # (b,hkv,g,sq,dv)
+    out = jnp.moveaxis(out, 3, 1).reshape(b, sq, hq, dv)
     return out.astype(q.dtype)
 
 
@@ -109,13 +110,13 @@ def decode_attention(q: jnp.ndarray, cache_k: jnp.ndarray,
 # ---------------------------------------------------------------------------
 
 def _flash_fwd_scan(qg, k, v, *, causal: bool, chunk: int, q_offset: int):
-    """qg (b,sq,hkv,g,hd) pre-scaled fp32; k/v (b,skv,hkv,hd).
-    Returns (out (b,hkv,g,sq,hd) fp32, lse (b,hkv,g,sq))."""
+    """qg (b,sq,hkv,g,hd) pre-scaled fp32; k (b,skv,hkv,hd); v
+    (b,skv,hkv,dv).  Returns (out (b,hkv,g,sq,dv) fp32, lse (b,hkv,g,sq))."""
     b, sq, hkv, g, hd = qg.shape
-    skv = k.shape[1]
+    skv, dv = k.shape[1], v.shape[-1]
     n_chunks = skv // chunk
     kc = jnp.moveaxis(k.reshape(b, n_chunks, chunk, hkv, hd), 1, 0)
-    vc = jnp.moveaxis(v.reshape(b, n_chunks, chunk, hkv, hd), 1, 0)
+    vc = jnp.moveaxis(v.reshape(b, n_chunks, chunk, hkv, dv), 1, 0)
     q_pos = q_offset + jnp.arange(sq)
 
     def body(carry, xs):
@@ -136,7 +137,7 @@ def _flash_fwd_scan(qg, k, v, *, causal: bool, chunk: int, q_offset: int):
 
     init = (jnp.full((b, hkv, g, sq), NEG_INF, jnp.float32),
             jnp.zeros((b, hkv, g, sq), jnp.float32),
-            jnp.zeros((b, hkv, g, sq, hd), jnp.float32))
+            jnp.zeros((b, hkv, g, sq, dv), jnp.float32))
     (m, l, acc), _ = jax.lax.scan(body, init,
                                   (kc, vc, jnp.arange(n_chunks)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
@@ -147,18 +148,19 @@ def _flash_fwd_scan(qg, k, v, *, causal: bool, chunk: int, q_offset: int):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def _flash_core(q, k, v, causal: bool, chunk: int, q_offset: int):
     b, sq, hq, hd = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
+    hkv, dv = k.shape[2], v.shape[-1]
     qg = _group(q, hkv).astype(jnp.float32) / jnp.sqrt(hd)
     out, _ = _flash_fwd_scan(qg, k, v, causal=causal, chunk=chunk,
                              q_offset=q_offset)
-    out = jnp.moveaxis(out, 3, 1).reshape(b, sq, hq, hd)
+    out = jnp.moveaxis(out, 3, 1).reshape(b, sq, hq, dv)
     return out.astype(q.dtype)
 
 
 def flash_attention(q, k, v, causal: bool = True, chunk: int = 1024,
                     q_offset: int = 0):
-    """Memory-linear attention.  q (b,sq,hq,hd); k,v (b,skv,hkv,hd).
-    Matches ``chunked_attention`` to fp32 accumulation accuracy; ragged
+    """Memory-linear attention.  q (b,sq,hq,hd); k (b,skv,hkv,hd); v
+    (b,skv,hkv,dv), ``dv`` free (latent attention: 192 against 128); the
+    scale is 1/√hd.  Returns (b,sq,hq,dv).  Matches ``chunked_attention`` to fp32 accumulation accuracy; ragged
     sequence lengths fall back to the reference path."""
     skv = k.shape[1]
     chunk = min(chunk, skv)
@@ -170,11 +172,11 @@ def flash_attention(q, k, v, causal: bool = True, chunk: int = 1024,
 
 def _flash_fwd(q, k, v, causal, chunk, q_offset):
     b, sq, hq, hd = q.shape
-    skv, hkv = k.shape[1], k.shape[2]
+    hkv, dv = k.shape[2], v.shape[-1]
     qg = _group(q, hkv).astype(jnp.float32) / jnp.sqrt(hd)
     o, lse = _flash_fwd_scan(qg, k, v, causal=causal, chunk=chunk,
                              q_offset=q_offset)
-    out = jnp.moveaxis(o, 3, 1).reshape(b, sq, hq, hd).astype(q.dtype)
+    out = jnp.moveaxis(o, 3, 1).reshape(b, sq, hq, dv).astype(q.dtype)
     return out, (qg, k, v, o, lse)
 
 
@@ -182,14 +184,14 @@ def _flash_bwd(causal, chunk, q_offset, res, dout):
     qg, k, v, o, lse = res
     qdt = v.dtype
     b, sq, hkv, g, hd = qg.shape
-    skv = k.shape[1]
+    skv, dv = k.shape[1], v.shape[-1]
     n_chunks = skv // chunk
     do = jnp.moveaxis(
-        dout.astype(jnp.float32).reshape(b, sq, hkv, g, hd), 1, 3)
+        dout.astype(jnp.float32).reshape(b, sq, hkv, g, dv), 1, 3)
     # D_q = rowsum(do ⊙ o)
     D = jnp.sum(do * o, axis=-1)                      # (b,hkv,g,sq)
     kc = jnp.moveaxis(k.reshape(b, n_chunks, chunk, hkv, hd), 1, 0)
-    vc = jnp.moveaxis(v.reshape(b, n_chunks, chunk, hkv, hd), 1, 0)
+    vc = jnp.moveaxis(v.reshape(b, n_chunks, chunk, hkv, dv), 1, 0)
     q_pos = q_offset + jnp.arange(sq)
 
     def body(dq, xs):
@@ -213,8 +215,8 @@ def _flash_bwd(causal, chunk, q_offset, res, dout):
     scale = 1.0 / jnp.sqrt(hd)
     dq = (dq * scale).reshape(b, sq, hkv * g, hd).astype(qdt)
     dk = jnp.moveaxis(dk_st, 0, 1).reshape(b, skv, hkv, hd).astype(k.dtype)
-    dv = jnp.moveaxis(dv_st, 0, 1).reshape(b, skv, hkv, hd).astype(v.dtype)
-    return dq, dk, dv
+    dv_ = jnp.moveaxis(dv_st, 0, 1).reshape(b, skv, hkv, dv).astype(v.dtype)
+    return dq, dk, dv_
 
 
 _flash_core.defvjp(_flash_fwd, _flash_bwd)

@@ -121,7 +121,8 @@ def softmax_xent(logits: jnp.ndarray, labels: jnp.ndarray,
 
 
 def chunked_softmax_xent(x: jnp.ndarray, table: jnp.ndarray,
-                         labels: jnp.ndarray, chunk: int = 512) -> jnp.ndarray:
+                         labels: jnp.ndarray, chunk: int = 512,
+                         mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """Full-softmax mean token xent WITHOUT materializing (b·s, V) logits.
 
     Scans over SEQUENCE chunks (the scan axis must be unsharded: chunking
@@ -131,7 +132,8 @@ def chunked_softmax_xent(x: jnp.ndarray, table: jnp.ndarray,
     chunk: all-gather the s-slice over 'model' (Megatron-SP pattern),
     matmul against the vocab-sharded table so logits shard on V, reduce.
     ``jax.checkpoint`` on the body recomputes chunk logits in the
-    backward.  x (b, s, d); table (V, d) [vocab-sharded]; labels (b, s).
+    backward.  x (b, s, d); table (V, d) [vocab-sharded]; labels (b, s);
+    ``mask`` (b, s), where given: the mean over the positions it weights.
     """
     b, s, d = x.shape
     if s % chunk != 0:
@@ -139,18 +141,31 @@ def chunked_softmax_xent(x: jnp.ndarray, table: jnp.ndarray,
     nc = s // chunk
 
     def body(acc, xs):
-        xc, lc = xs                                  # (b, chunk, d), (b, chunk)
+        xc, lc = xs[:2]                              # (b, chunk, d), (b, chunk)
         xc = shard_act(xc, None, None)               # gather s over 'model'
-        logits = jnp.einsum("bcd,vd->bcv", xc, table.astype(xc.dtype))
-        logits = shard_act(logits, None, "model").astype(jnp.float32)
+        # the f32 table straight into the matmul: no half-precision copy
+        # of it is materialised (the chip multiplies f32 operands at the
+        # default precision in bf16 passes), and its gradient comes out
+        # in f32
+        logits = jnp.einsum("bcd,vd->bcv", xc, table,
+                            preferred_element_type=jnp.float32)
+        logits = shard_act(logits, None, "model")
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, lc[..., None], axis=-1)[..., 0]
-        return acc + jnp.sum(logz - gold), None
+        nll = logz - gold
+        if mask is not None:
+            nll = nll * xs[2]
+        return acc + jnp.sum(nll), None
 
     body = jax.checkpoint(body, prevent_cse=False)
     xs = (jnp.moveaxis(x.reshape(b, nc, chunk, d), 1, 0),
           jnp.moveaxis(labels.reshape(b, nc, chunk), 1, 0))
+    if mask is not None:
+        xs += (jnp.moveaxis(mask.astype(jnp.float32).reshape(b, nc, chunk),
+                            1, 0),)
     total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), xs)
+    if mask is not None:
+        return total / jnp.maximum(jnp.sum(mask.astype(jnp.float32)), 1.0)
     return total / (b * s)
 
 

@@ -26,8 +26,15 @@ class ArchConfig:
     repeat_kv: bool = False      # replicate KV heads to hq for clean TP
     rope_theta: float = 10000.0
     norm: str = "rmsnorm"        # rmsnorm | layernorm
+    norm_eps: float = 1e-6
     act: str = "silu"            # silu | gelu
     tie_embeddings: bool = False
+
+    # --- latent attention (MLA, DeepSeek-V2 §2.1) ----------------------------
+    kv_lora_rank: int = 0        # > 0: MLA with a KV latent this wide
+    qk_nope_head_dim: int = 0    # per-head q/k width without rotary
+    qk_rope_head_dim: int = 0    # per-head q width with rotary; k's is shared
+    v_head_dim: int = 0
 
     # --- MoE -------------------------------------------------------------
     n_experts: int = 0
@@ -39,6 +46,19 @@ class ArchConfig:
     dense_d_ff: int = 0          # d_ff of interleaved dense layers (moe_every>1)
     fsdp: bool = False           # shard master weights over data/pod (llama4)
     moe_groups: int = 32         # grouped dispatch (aligned with DP shards)
+    first_k_dense: int = 0       # leading dense-FFN layers (d_ff dense_d_ff)
+    # softmax: top-k of the softmax, capacity-bounded dispatch; sigmoid:
+    # DeepSeek-V3's aux-loss-free router (top-k of sigmoid scores plus a
+    # bias buffer, gates renormalised and scaled), dropless over the
+    # experts held here
+    router: str = "softmax"
+    routed_scaling: float = 1.0
+    bias_update_rate: float = 0.0    # γ of the router bias update
+    aux_loss_alpha: float = 0.01     # weight of the balance loss
+    # expert parallelism: this chip holds experts
+    # [expert_rank·n_experts_held, (expert_rank+1)·n_experts_held); 0 = all
+    n_experts_held: int = 0
+    expert_rank: int = 0
 
     # --- SSM / hybrid ----------------------------------------------------
     ssm_state: int = 0           # Mamba2 N
@@ -60,6 +80,9 @@ class ArchConfig:
     attn_chunk: int = 1024
     loss_chunk: int = 512        # chunked-xent seq-chunk (see common.py)
     softmax_samples: int = 8192  # negatives for sampled softmax (paper §7.2)
+    # train tok_embed through the sparse-rows step: its gradient leaves the
+    # loss as (ids, rows) and the dedup + sketch kernel update the table
+    sparse_embedding: bool = False
 
     # --- count-sketch optimizer integration -------------------------------
     sketch_compression: float = 5.0
@@ -78,6 +101,14 @@ class ArchConfig:
     @property
     def dtype(self):
         return jnp.dtype(self.compute_dtype)
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def experts_held(self) -> int:
+        return self.n_experts_held or self.n_experts
 
     @property
     def ssm_d_inner(self) -> int:
@@ -117,6 +148,19 @@ class ArchConfig:
             name=self.name + "-smoke",
             aux_budget_bytes=None,   # full-size budgets don't scale down
         )
+        if self.mla:
+            small.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                         qk_rope_head_dim=8, v_head_dim=16, head_dim=24,
+                         first_k_dense=min(self.first_k_dense, 1),
+                         n_layers=min(self.n_layers,
+                                      min(self.first_k_dense, 1) + 2))
+        if self.router == "sigmoid":
+            # 8 experts, 2 a token, in shares of the published ratio
+            held = self.experts_held * 8 // self.n_experts
+            small.update(n_experts=8, top_k=min(self.top_k, 2),
+                         n_experts_held=max(held, 1) if held < 8 else 0,
+                         expert_rank=0, shared_d_ff=64 if self.shared_d_ff
+                         else 0, d_ff=32)
         small.update(overrides)
         return dataclasses.replace(self, **small)
 

@@ -12,6 +12,11 @@ two gathers.  Capacity overflow drops tokens (standard).  Sharding:
 
 Shared experts (qwen2-moe: 4 merged into one wide SwiGLU; llama4: 1) are a
 plain dense FFN added to the routed output.
+
+``cfg.router == 'sigmoid'`` is DeepSeek-V3's router (arXiv:2412.19437
+§2.1.2) over the experts this chip holds (``moe_apply_held``): it scores
+all ``n_experts``, computes only its own experts' part of the result and
+drops nothing.
 """
 from __future__ import annotations
 
@@ -22,13 +27,14 @@ import jax.numpy as jnp
 
 from repro.models import common as cm
 from repro.models.config import ArchConfig
+from repro.obs.profiling import scope
 
 
 def moe_init(key, cfg: ArchConfig):
     ks = jax.random.split(key, 7)
-    E, d, f = cfg.n_experts, cfg.d_model, cfg.d_ff
+    E, d, f = cfg.experts_held, cfg.d_model, cfg.d_ff
     p = {
-        "router": cm.dense_init(ks[0], d, E, scale=0.02),
+        "router": cm.dense_init(ks[0], d, cfg.n_experts, scale=0.02),
         "w_gate": (jax.random.normal(ks[1], (E, d, f), jnp.float32)
                    / jnp.sqrt(d)).astype(jnp.float32),
         "w_up": (jax.random.normal(ks[2], (E, d, f), jnp.float32)
@@ -42,6 +48,9 @@ def moe_init(key, cfg: ArchConfig):
             "w_up": cm.dense_init(ks[5], d, cfg.shared_d_ff),
             "w_down": cm.dense_init(ks[6], cfg.shared_d_ff, d),
         }
+    if cfg.router == "sigmoid":
+        # a buffer, not a weight: the step moves it by the loads it sees
+        p["router_bias"] = jnp.zeros((cfg.n_experts,), jnp.float32)
     return p
 
 
@@ -177,3 +186,169 @@ def _shard(x, axes):
     from jax.sharding import PartitionSpec as P
     from repro.distributed.sharding import constraint
     return constraint(x, P(*axes))
+
+
+# ---------------------------------------------------------------------------
+# DeepSeek-V3 routing over the experts held here (expert parallelism)
+# ---------------------------------------------------------------------------
+
+def route_sigmoid(cfg: ArchConfig, p, x: jnp.ndarray):
+    """x (T, d) -> (scores (T, E) f32, expert ids (T, K), gates (T, K)).
+    Scores are sigmoid(x W_r) in float32, as the published gate computes
+    them; the top-K is chosen on score + bias, the gates are the chosen
+    raw scores, normalised to sum one and scaled by ``routed_scaling``."""
+    logits = x.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    scores = jax.nn.sigmoid(logits)
+    bias = jax.lax.stop_gradient(p["router_bias"])
+    _, eids = jax.lax.top_k(scores + bias, cfg.top_k)
+    w = jnp.take_along_axis(scores, eids, axis=-1)
+    gates = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return scores, eids, gates * cfg.routed_scaling
+
+
+def balance_loss(cfg: ArchConfig, scores: jnp.ndarray,
+                 eids: jnp.ndarray) -> jnp.ndarray:
+    """The sequence-wise balance loss Σ_i f_i·P_i, averaged over the batch
+    (unweighted; the caller multiplies by α).  scores (b, s, E), eids
+    (b, s, K): f_i = E/(K·s)·#{t: i chosen at t}, P_i = mean_t s_i,t / Σ_j
+    s_j,t, both per sequence."""
+    b, s, E = scores.shape
+    chosen = jax.vmap(lambda e: jnp.zeros((E,), jnp.float32).at[
+        e.reshape(-1)].add(1.0))(eids)                        # (b, E)
+    f = chosen * (E / (cfg.top_k * s))
+    P = jnp.mean(scores / jnp.sum(scores, axis=-1, keepdims=True), axis=1)
+    return jnp.mean(jnp.sum(f * P, axis=-1))
+
+
+def expert_loads(cfg: ArchConfig, eids: jnp.ndarray) -> jnp.ndarray:
+    """(E,) int32: the (token, slot) pairs each expert received."""
+    return jnp.zeros((cfg.n_experts,), jnp.int32).at[
+        eids.reshape(-1)].add(1)
+
+
+def bias_update(cfg: ArchConfig, bias: jnp.ndarray,
+                loads: jnp.ndarray) -> jnp.ndarray:
+    """b_i += γ·sign(mean load − load_i): underloaded experts gain.
+    ``loads`` (..., E), the mean over the last axis."""
+    load = loads.astype(jnp.float32)
+    mean = jnp.mean(load, axis=-1, keepdims=True)
+    return bias + cfg.bias_update_rate * jnp.sign(mean - load)
+
+
+def grouped_matmul_backend() -> str:
+    """``gmm``: the megablox Pallas kernel (a TPU), whose grid runs only the
+    row tiles of non-empty groups; ``ragged_dot`` elsewhere.  (Tests name
+    ``interpret``: the kernel under the Pallas interpreter.)"""
+    return "gmm" if jax.default_backend() == "tpu" else "ragged_dot"
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    """(tm, tk, tn) for a megablox call: 512-row tiles, and each of k, n
+    whole or in 512-wide tiles (1,408 = 11·128 stays whole)."""
+    tm = next(t for t in (512, 256, 128, 64, 32, 16, 8) if m % t == 0)
+    return (tm, 512 if k % 512 == 0 else k, 512 if n % 512 == 0 else n)
+
+
+def grouped_matmul(lhs: jnp.ndarray, rhs: jnp.ndarray,
+                   group_sizes: jnp.ndarray) -> jnp.ndarray:
+    """lhs (m, k) rows in groups of ``group_sizes`` (G,), rhs (G, k, n):
+    row r of group g times rhs[g]; rows past Σ group_sizes are padding,
+    and their output is undefined (the caller masks it)."""
+    backend = grouped_matmul_backend()
+    if backend in ("gmm", "interpret"):
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+        return gmm(lhs, rhs, group_sizes, lhs.dtype, _gmm_tiling, None,
+                   None, False, backend == "interpret")
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=lhs.dtype)
+
+
+def dispatch_held(cfg: ArchConfig, eids: jnp.ndarray):
+    """Sort the (token, slot) pairs so those routed to the held experts
+    come first, grouped by expert.  Returns (order (T·K,), the token of
+    each sorted pair, group sizes (held,) int32, how many are here)."""
+    T, K = eids.shape
+    held = cfg.experts_held
+    local = eids.reshape(-1) - cfg.expert_rank * held
+    here = (local >= 0) & (local < held)
+    key = jnp.where(here, local, held).astype(jnp.int32)
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((held + 1,), jnp.int32).at[key].add(1)[:held]
+    tokens = (order // K).astype(jnp.int32)
+    return order, tokens, sizes, jnp.sum(sizes)
+
+
+def _held_chunk(cfg: ArchConfig, p, xt, eids, gates):
+    """The held experts' part of the result for one chunk of tokens: xt
+    (Tc, d), eids and gates (Tc, K).  Returns (y (Tc, d), pairs here)."""
+    Tc, d = xt.shape
+    dt = xt.dtype
+    act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
+    with scope("obs.router"):
+        order, tok, sizes, n_here = dispatch_held(cfg, eids)
+        valid = (jnp.arange(Tc * cfg.top_k) < n_here)[:, None]
+        xs = jnp.where(valid, xt[tok], 0).astype(dt)
+    with scope("obs.experts"):
+        h = act(grouped_matmul(xs, p["w_gate"].astype(dt), sizes)) * \
+            grouped_matmul(xs, p["w_up"].astype(dt), sizes)
+        ye = grouped_matmul(h, p["w_down"].astype(dt), sizes)
+    with scope("obs.router"):
+        # padding rows of the kernels' outputs are never written: mask
+        # them before anything multiplies them, in the backward too
+        ye = jnp.where(valid, ye, 0).astype(jnp.float32)
+        ye = (ye * gates.reshape(-1)[order][:, None]).astype(dt)
+        y = jnp.zeros((Tc, d), dt).at[tok].add(ye)
+    return y, n_here
+
+
+# (token, slot) rows one dispatch chunk holds: 4,096 tokens of 6 slots
+# keep the chunk's buffers near 100 MB at hidden size 2,048
+CHUNK_ROWS = 32768
+
+
+def token_chunks(cfg: ArchConfig, T: int) -> int:
+    """How many chunks the routed part runs in: the fewest that keep each
+    chunk's (token, slot) buffer within ``CHUNK_ROWS`` rows."""
+    n = 1
+    while T % (2 * n) == 0 and (T // n) * cfg.top_k > CHUNK_ROWS:
+        n *= 2
+    return n
+
+
+def moe_apply_held(cfg: ArchConfig, p, x: jnp.ndarray):
+    """x (b, s, d) -> (y (b, s, d), balance loss, stats).
+
+    Routes every token over all ``n_experts`` and computes the part of the
+    result that the held experts give, for every (token, slot) pair routed
+    to one of them (none dropped), plus the shared experts.  The routed
+    part runs over chunks of tokens (``token_chunks``), each rematerialised
+    in the backward, so that one chunk's dispatch buffer is live at a time.
+    ``stats``: ``loads`` (E,) for the bias update and ``routed_here``, the
+    pairs the held experts computed.  Scopes: ``obs.router`` (scores,
+    top-k, sort, dispatch, combine) and ``obs.experts`` (the held experts'
+    grouped matmuls)."""
+    b, s, d = x.shape
+    T = b * s
+    xt = x.reshape(T, d)
+    dt = x.dtype
+    with scope("obs.router"):
+        scores, eids, gates = route_sigmoid(cfg, p, xt)
+        aux = balance_loss(cfg, scores.reshape(b, s, -1),
+                           eids.reshape(b, s, -1))
+    n = token_chunks(cfg, T)
+    if n == 1:
+        y, n_here = _held_chunk(cfg, p, xt, eids, gates)
+    else:
+        body = jax.checkpoint(
+            lambda xs: _held_chunk(cfg, p, *xs), prevent_cse=False)
+        y, per = jax.lax.map(body, (xt.reshape(n, T // n, d),
+                                    eids.reshape(n, T // n, -1),
+                                    gates.reshape(n, T // n, -1)))
+        y, n_here = y.reshape(T, d), jnp.sum(per)
+    if cfg.shared_d_ff:
+        act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
+        sp = p["shared"]
+        hs = act(xt @ sp["w_gate"].astype(dt)) * (xt @ sp["w_up"].astype(dt))
+        y = y + hs @ sp["w_down"].astype(dt)
+    stats = {"loads": expert_loads(cfg, eids), "routed_here": n_here}
+    return y.reshape(b, s, d), aux, stats

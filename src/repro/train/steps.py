@@ -153,25 +153,48 @@ def make_train_step(cfg: ArchConfig, *, optimizer: str = "cs_adam",
     params/optimizer state replicated in the body, and the gradient
     moved by explicit ``pmean`` collectives.  The step must then be
     TRACED inside ``shd.active_mesh(mesh)`` (launch/train.py --dp does);
-    per-replica loss is pmean'd so metrics match the global-batch step."""
+    per-replica loss is pmean'd so metrics match the global-batch step.
+
+    A sigmoid-routed config (``cfg.router``) moves its router bias buffers
+    after the update, by the step's expert loads, and reports
+    ``routed_here`` (the (token, slot) pairs the held experts computed).
+    ``cfg.sparse_embedding`` trains ``tok_embed`` through the sparse-rows
+    step (``sparse_lm_step``)."""
+    if cfg.sparse_embedding:
+        if plan is not None or dp_axis is not None or sampled_softmax \
+                or grad_clip is not None or optimizer != "cs_adam":
+            raise ValueError(
+                "a sparse-embedding LM step takes optimizer 'cs_adam' with "
+                "no plan, dp axis, sampled softmax or gradient clipping: "
+                "the embedding's gradient leaves the tree as rows, so a "
+                "global norm over the tree would miss it")
+        return sparse_lm_step(cfg, lr=lr, remat=remat, cleaning=cleaning,
+                              kernel_backend=kernel_backend)
     mod = family_module(cfg)
     opt = build_optimizer(cfg, optimizer, lr=lr, cleaning=cleaning,
                           kernel_backend=kernel_backend, plan=plan)
     clip = (opt_lib.clip_by_global_norm(grad_clip)
             if grad_clip is not None else (lambda g: g))
+    routed = cfg.router == "sigmoid"
 
     def loss_fn(params, batch):
+        if routed:
+            return mod.train_loss_stats(cfg, params, batch, remat=remat,
+                                        sampled_softmax=sampled_softmax)
         return mod.train_loss(cfg, params, batch, remat=remat,
-                              sampled_softmax=sampled_softmax)
+                              sampled_softmax=sampled_softmax), {}
 
     def step_body(params, opt_state, batch):
         with scope("obs.grad"):
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            (loss, stats), grads = jax.value_and_grad(
+                loss_fn, has_aux=True)(params, batch)
         if dp_axis is not None:
             with scope("obs.collective"):
                 loss = jax.lax.pmean(loss, dp_axis)
                 grads = jax.tree_util.tree_map(
                     lambda g: jax.lax.pmean(g, dp_axis), grads)
+                stats = jax.tree_util.tree_map(
+                    lambda x: jax.lax.psum(x, dp_axis), stats)
         grads = clip(grads)
         with scope("obs.kernel"):
             updates, opt_state = opt.update(grads, opt_state, params)
@@ -179,6 +202,9 @@ def make_train_step(cfg: ArchConfig, *, optimizer: str = "cs_adam",
         gn = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
                           for g in jax.tree_util.tree_leaves(grads)))
         metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gn}
+        if routed:
+            params, metrics["routed_here"] = _after_routing(cfg, params,
+                                                            stats)
         return params, opt_state, metrics
 
     if dp_axis is None:
@@ -210,6 +236,85 @@ def make_train_step(cfg: ArchConfig, *, optimizer: str = "cs_adam",
                      store_tree=plan.store_tree() if plan is not None
                      else None,
                      dp_axis=dp_axis)
+
+
+def _after_routing(cfg: ArchConfig, params, stats):
+    """Move the router bias buffers by the step's expert loads; return
+    the params and ``routed_here``."""
+    from repro.models import transformer
+    with scope("obs.router"):
+        params = transformer.with_router_bias(cfg, params, stats["loads"])
+    return params, stats["routed_here"].astype(jnp.float32)
+
+
+EMBED_PATH = "tok_embed/table"
+
+
+def sparse_lm_step(cfg: ArchConfig, *, lr=1e-3, remat: bool = True,
+                   cleaning: Optional[CleaningSchedule] = None,
+                   kernel_backend: Optional[str] = None) -> TrainStep:
+    """The LM step whose embedding table trains in the paper's (ids,
+    grad-rows) regime.  The looked-up rows are gathered in float32 and
+    cast; the gradient with respect to them goes, with the ids, through
+    ``make_sparse_embedding_step``'s own step (dedup, count-sketch Adam
+    kernel, table write: scopes ``obs.dedup``, ``obs.kernel``,
+    ``obs.apply``).  Every other leaf, the output head included, takes
+    ``build_optimizer(cfg, 'cs_adam')`` (the head's moments in sketches,
+    the rest dense).  State: ``{'rest': ..., 'tok_embed': ...}``."""
+    mod = family_module(cfg)
+    rest_opt = build_optimizer(cfg, "cs_adam", lr=lr, cleaning=cleaning,
+                               kernel_backend=kernel_backend)
+    hp = SketchHParams(compression=cfg.sketch_compression,
+                       depth=cfg.sketch_depth, backend=kernel_backend)
+    _, embed_step, embed_opt = make_sparse_embedding_step(
+        cfg.vocab, cfg.d_model, lr=lr, hparams=hp, path=EMBED_PATH)
+    routed = cfg.router == "sigmoid"
+
+    def split(params):
+        return params["tok_embed"]["table"], {
+            k: v for k, v in params.items() if k != "tok_embed"}
+
+    def init(params):
+        return {"rest": rest_opt.init(split(params)[1]),
+                "tok_embed": embed_opt.init()}
+
+    def update(*_):
+        raise NotImplementedError("the sparse-embedding LM step applies "
+                                  "its own updates")
+
+    def step_fn(params, opt_state, batch):
+        table, rest = split(params)
+        b, s = batch["tokens"].shape
+        ids = batch["tokens"].reshape(-1).astype(jnp.int32)
+        rows = table[ids]
+
+        def loss_fn(rest, rows):
+            x = rows.reshape(b, s, -1).astype(cfg.dtype)
+            return mod.train_loss_stats(cfg, rest, batch, remat=remat, x=x)
+
+        with scope("obs.grad"):
+            (loss, stats), (g_rest, g_rows) = jax.value_and_grad(
+                loss_fn, argnums=(0, 1), has_aux=True)(rest, rows)
+        with scope("obs.kernel"):
+            updates, rest_state = rest_opt.update(g_rest, opt_state["rest"],
+                                                  rest)
+        with scope("obs.apply"):
+            rest = opt_lib.apply_updates(rest, updates)
+        table, embed_state = embed_step(table, opt_state["tok_embed"], ids,
+                                        g_rows)
+        gn = jnp.sqrt(sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                          for g in jax.tree_util.tree_leaves(
+                              (g_rest, g_rows))))
+        metrics = {"loss": loss.astype(jnp.float32), "grad_norm": gn}
+        if routed:
+            rest, metrics["routed_here"] = _after_routing(cfg, rest, stats)
+        params = dict(rest, tok_embed={"table": table})
+        return params, {"rest": rest_state, "tok_embed": embed_state}, \
+            metrics
+
+    return TrainStep(cfg=cfg, init_fn=lambda rng: mod.init(rng, cfg),
+                     step_fn=step_fn, optimizer=Transform(init, update),
+                     batch_template={})
 
 
 def resolve_sparse_stores(stores, path: str, shape: Tuple[int, int]):

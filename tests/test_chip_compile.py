@@ -48,12 +48,14 @@ def _kernels_in(compiled) -> int:
 
 
 # (depth, width, dim, rows per batch): real widths, the deduped 65,536-id
-# batch, the 151,936-row vocab, depths other than 1 and 3
+# batch, the 151,936-row vocab, depths other than 1 and 3, Moonlight's
+# 163,840-row vocab at hidden size 2,048
 ADAM_SHAPES = [(3, 16384, 128, 1024), (3, 16384, 128, 16384),
                (1, 65536, 128, 1024), (3, 65536, 128, 32768),
                (4, 16384, 128, 1024), (5, 16384, 128, 1024),
                (3, 16384, 256, 1024), (3, 16384, 512, 1024),
-               (3, 16384, 896, 1024), (3, 29952, 896, 151936)]
+               (3, 16384, 896, 1024), (3, 29952, 896, 151936),
+               (3, 11008, 2048, 16384)]
 
 
 @pytest.mark.parametrize("depth,width,dim,k", ADAM_SHAPES)
@@ -209,3 +211,28 @@ def test_registry_resolves_by_spec_on_tpu(monkeypatch, dim, dtype, pair,
         else:
             with pytest.raises(ValueError, match="cannot run"):
                 registry.resolve(kind, op, "tiled", specs)
+
+
+def test_expert_grouped_matmuls_compile_at_moonlight_widths(one_chip,
+                                                           monkeypatch):
+    """One dispatch chunk of Moonlight's held experts (24,576 (token, slot)
+    rows, 8 experts of 2,048 x 1,408) forward and backward through the
+    megablox kernel, at the tiles ``moe._gmm_tiling`` picks."""
+    from repro import configs
+    from repro.models import moe
+    monkeypatch.setattr(moe, "grouped_matmul_backend", lambda: "gmm")
+    cfg = configs.get("moonlight-16b-a3b-ep8")
+    bf, i32 = jnp.bfloat16, jnp.int32
+    rows, d, f = 4096 * cfg.top_k, cfg.d_model, cfg.d_ff
+
+    def chunk(xs, wg, wu, wd, sizes):
+        h = jax.nn.silu(moe.grouped_matmul(xs, wg, sizes)) * \
+            moe.grouped_matmul(xs, wu, sizes)
+        return jnp.sum(moe.grouped_matmul(h, wd, sizes).astype(
+            jnp.float32))
+
+    compiled = jax.jit(jax.grad(chunk, (0, 1, 2, 3))).lower(
+        _sds((rows, d), bf, one_chip), _sds((8, d, f), bf, one_chip),
+        _sds((8, d, f), bf, one_chip), _sds((8, f, d), bf, one_chip),
+        _sds((8,), i32, one_chip)).compile()
+    assert _kernels_in(compiled) >= 6
