@@ -427,6 +427,11 @@ class manual_collectives:
         return False
 
 
+def in_manual_region() -> bool:
+    """Whether tracing is inside a ``manual_collectives`` region."""
+    return bool(_MANUAL_DEPTH)
+
+
 def dp_sparse_wrap(local_fn, *, mesh: Optional[Mesh] = None,
                    dp_axis: str = "data"):
     """The one-table sparse DP calling convention, in one place: wrap

@@ -1,13 +1,28 @@
-"""GQA attention: flash (custom-vjp) training path + KV-cache serving path.
+"""GQA attention: flash training path (a Pallas kernel on a TPU, a
+custom-vjp scan elsewhere) + KV-cache serving path.
 
-``flash_attention`` is the production path: online-softmax over KV
-chunks, and a ``custom_vjp`` whose forward saves only (o, logsumexp) —
-the backward re-forms each chunk's probabilities instead of storing the
-(s × s) matrix.  Without the custom vjp, the inner scan stacks per-chunk
-softmax residuals for autodiff: a 4k-seq layer stores the full s² f32
-attention matrix (~4.5 GiB/device at the train_4k cells — measured in
-EXPERIMENTS.md §Perf), defeating the point of chunking.  Peak is now
-O(s·chunk + s·d); HBM traffic O(s²·d / chunk).
+``flash_attention`` is the production path.  It picks one of two cores
+from what it observes (``use_kernel``):
+
+* **kernel** — causal self-attention on a TPU runs splash attention
+  (``jax.experimental.pallas.ops.tpu.splash_attention``): each
+  (query block, key block) tile of scores and probabilities lives in
+  VMEM only, blocks wholly above the diagonal are skipped, and the
+  forward, ``dq`` and ``dkv`` are the kernel's own, with (o, logsumexp)
+  as residuals.  Operands go in as bf16 (q pre-scaled by 1/√hd in f32)
+  and accumulate in f32: the products the scan's f32 einsums get from
+  the MXU at the default precision, which rounds each operand to bf16.
+* **scan** — ``_flash_core``, an online softmax over KV chunks with a
+  ``custom_vjp`` whose forward saves only (o, logsumexp); the backward
+  re-forms each chunk's probabilities instead of storing the (s × s)
+  matrix (without it, a 4k-seq layer stores the full s² f32 attention
+  matrix, ~4.5 GiB/device at the train_4k cells — EXPERIMENTS.md §Perf).
+  It runs everywhere the kernel does not: off a TPU (the CPU), under a
+  mesh GSPMD would split the call over (GSPMD does not partition a
+  ``pallas_call``; ``_sharded_attention`` shards heads or the
+  q-sequence over 'model'), at lengths no kernel block divides, for
+  prefill continuation (``q_offset``, sq ≠ skv) and for non-causal or
+  cross attention (``encdec.py``).  Its chunk is ``attn_chunk``.
 
 ``chunked_attention`` (plain scan, autodiff backward) is kept as the
 reference oracle for tests.  Decode attends one query against the full
@@ -22,7 +37,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+import math
+from typing import Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -160,14 +176,83 @@ def flash_attention(q, k, v, causal: bool = True, chunk: int = 1024,
                     q_offset: int = 0):
     """Memory-linear attention.  q (b,sq,hq,hd); k (b,skv,hkv,hd); v
     (b,skv,hkv,dv), ``dv`` free (latent attention: 192 against 128); the
-    scale is 1/√hd.  Returns (b,sq,hq,dv).  Matches ``chunked_attention`` to fp32 accumulation accuracy; ragged
-    sequence lengths fall back to the reference path."""
+    scale is 1/√hd.  Returns (b,sq,hq,dv).  On a TPU, causal
+    self-attention runs the Pallas kernel (``use_kernel``); elsewhere the
+    scan, which matches ``chunked_attention`` to fp32 accumulation
+    accuracy; ragged sequence lengths fall back to the reference path."""
+    from repro.distributed import sharding
+    mesh = sharding.current_mesh()
+    if use_kernel(platform=jax.default_backend(), causal=causal,
+                  sq=q.shape[1], skv=k.shape[1], q_offset=q_offset,
+                  hq=q.shape[2], hkv=k.shape[2],
+                  mesh=None if mesh is None else
+                  dict(zip(mesh.axis_names, mesh.devices.shape)),
+                  manual=sharding.in_manual_region()):
+        return kernel_attention(q, k, v, block=kernel_block(q.shape[1]))
     skv = k.shape[1]
     chunk = min(chunk, skv)
     if skv % chunk != 0:
         return chunked_attention(q, k, v, causal=causal, chunk=chunk,
                                  q_offset=q_offset)
     return _flash_core(q, k, v, causal, chunk, q_offset)
+
+
+# ---------------------------------------------------------------------------
+# The Pallas kernel path (splash attention, TPU)
+# ---------------------------------------------------------------------------
+
+KERNEL_BLOCKS = (1024, 512, 256, 128)    # largest first; 128: the lane width
+
+
+def kernel_block(s: int) -> Optional[int]:
+    """The kernel's query and key block for sequence length ``s``: the
+    largest of ``KERNEL_BLOCKS`` that divides it, or None."""
+    return next((blk for blk in KERNEL_BLOCKS if s % blk == 0), None)
+
+
+def use_kernel(*, platform: str, causal: bool, sq: int, skv: int,
+               q_offset: int, hq: int, hkv: int,
+               mesh: Optional[Mapping[str, int]] = None,
+               manual: bool = False) -> bool:
+    """Whether ``flash_attention`` runs the Pallas kernel: causal
+    self-attention (sq == skv, no offset) on a TPU, at a length a kernel
+    block divides, with whole GQA groups, where GSPMD would not split the
+    call.  ``mesh`` is the active mesh's axis sizes (None: no mesh);
+    ``manual``: traced inside a ``shard_map`` body, where every array is
+    already one device's."""
+    ways = 1 if mesh is None or manual else math.prod(mesh.values())
+    return (platform == "tpu" and causal and sq == skv and q_offset == 0
+            and kernel_block(sq) is not None and hq % hkv == 0
+            and ways == 1)
+
+
+@functools.lru_cache(maxsize=None)
+def _splash_kernel(heads: int, s: int, block: int, interpret: bool):
+    """Splash attention over ``heads`` heads of length ``s`` with a causal
+    mask, built once per shape; fully masked blocks are skipped."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk, splash_attention_mask as sm)
+    mask = sm.MultiHeadMask([sm.CausalMask((s, s))] * heads)
+    blocks = sk.BlockSizes(
+        block_q=block, block_kv=block, block_kv_compute=block,
+        block_q_dkv=block, block_kv_dkv=block, block_kv_dkv_compute=block,
+        block_q_dq=block, block_kv_dq=block)
+    with jax.ensure_compile_time_eval():   # mask tables: constants
+        return sk.make_splash_mha(mask, block_sizes=blocks, head_shards=1,
+                                  q_seq_shards=1, interpret=interpret)
+
+
+def kernel_attention(q, k, v, *, block: int, interpret: bool = False):
+    """Causal self-attention through the splash kernel, vmapped over the
+    batch.  q, k (b,s,hq|hkv,hd); v (b,s,hkv,dv); the scale is 1/√hd,
+    applied to q in f32 before the bf16 cast.  Returns (b,s,hq,dv) in
+    q's dtype; gradients flow through the kernel's own backward."""
+    b, s, hq, hd = q.shape
+    qs = (q.astype(jnp.float32) / jnp.sqrt(hd)).astype(jnp.bfloat16)
+    heads_first = lambda x: jnp.swapaxes(x.astype(jnp.bfloat16), 1, 2)
+    kernel = _splash_kernel(hq, s, block, interpret)
+    o = jax.vmap(kernel)(heads_first(qs), heads_first(k), heads_first(v))
+    return jnp.swapaxes(o, 1, 2).astype(q.dtype)
 
 
 def _flash_fwd(q, k, v, causal, chunk, q_offset):

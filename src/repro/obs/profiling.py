@@ -52,16 +52,29 @@ def scope_map(hlo_text: str) -> Dict[str, str]:
     """``{instruction: scope}`` over an HLO module's text (the optimized
     module of ``compiled.as_text()``, whose instruction names are those the
     trace's op events carry): each instruction's innermost ``obs.*``
-    component of its ``metadata={op_name=...}`` path, or ``UNSCOPED``."""
+    component of its ``metadata={op_name=...}`` path, or ``UNSCOPED``.
+    An instruction's text runs up to the next instruction: a Pallas
+    kernel's frontend attributes can hold line breaks, which put its
+    metadata on a later line."""
     out = {}
+
+    def add(name, text):
+        op = _OP_NAME.search(text)
+        path = op.group(1).split("/") if op else ()
+        out[name] = next((c for c in reversed(path) if c.startswith("obs.")),
+                         UNSCOPED)
+
+    name, text = None, []
     for line in hlo_text.splitlines():
         m = _INSTRUCTION.match(line)
         if m is None:
+            text.append(line)
             continue
-        op = _OP_NAME.search(line)
-        path = op.group(1).split("/") if op else ()
-        out[m.group(1)] = next((c for c in reversed(path)
-                                if c.startswith("obs.")), UNSCOPED)
+        if name is not None:
+            add(name, "\n".join(text))
+        name, text = m.group(1), [line]
+    if name is not None:
+        add(name, "\n".join(text))
     return out
 
 

@@ -236,3 +236,25 @@ def test_expert_grouped_matmuls_compile_at_moonlight_widths(one_chip,
         _sds((8, d, f), bf, one_chip), _sds((8, f, d), bf, one_chip),
         _sds((8,), i32, one_chip)).compile()
     assert _kernels_in(compiled) >= 6
+
+
+@pytest.mark.parametrize("b,s,hq,hkv,hd,dv", [(2, 8192, 16, 16, 192, 128),
+                                              (1, 1024, 14, 2, 64, 64)])
+def test_attention_kernel_compiles(one_chip, b, s, hq, hkv, hd, dv):
+    """The splash attention path of ``flash_attention``, forward and
+    backward: Moonlight's latent attention at 8,192 (a 192-wide q·k
+    contraction against a 128-wide v) and a GQA layer of 64-wide heads."""
+    from repro.models import attention as attn
+    bf = jnp.bfloat16
+
+    def loss(q, k, v):
+        o = attn.kernel_attention(q, k, v, block=attn.kernel_block(s))
+        return jnp.sum(o.astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        _sds((b, s, hq, hd), bf, one_chip), _sds((b, s, hkv, hd), bf,
+                                                 one_chip),
+        _sds((b, s, hkv, dv), bf, one_chip)).compile()
+    text = compiled.as_text()
+    for phase in ("fwd", "dq", "dkv"):
+        assert f"splash_mha_{phase}" in text, phase

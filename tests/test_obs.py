@@ -410,6 +410,24 @@ class TestProfiling:
             "p": "unscoped", "a.1": "obs.kernel", "b": "unscoped",
             "sort.2": "obs.dedup"}
 
+    def test_scope_map_reads_metadata_after_a_line_break(self):
+        """A Pallas kernel's frontend attributes hold line breaks: its
+        metadata comes lines after the instruction's name."""
+        text = "\n".join([
+            "ENTRY %main (p: f32[4]) -> f32[4] {",
+            "  %p = f32[4]{0} parameter(0)",
+            '  %splash_mha_fwd.3 = f32[4]{0} custom-call(%p), custom_call_'
+            'target="tpu_custom_call", frontend_attributes={kernel_'
+            'metadata={',
+            '"xprof_metadata":"{\\"block_q\\": 512}"',
+            '}}, metadata={op_name="jit(f)/obs.attn/vmap(jit(_splash))/'
+            'pallas_call"}',
+            '  ROOT %c = f32[4]{0} cosine(%splash_mha_fwd.3)',
+            "}"])
+        assert profiling.scope_map(text) == {
+            "p": "unscoped", "splash_mha_fwd.3": "obs.attn",
+            "c": "unscoped"}
+
     def test_scope_map_of_compiled_function(self):
         def f(x):
             with profiling.scope("obs.kernel"):
