@@ -22,7 +22,6 @@ BENCHES = [
     ("extreme", "Tab 8: MACH extreme classification"),
     ("extreme_scale", "Tab 8 at scale: batch sweep to the memory wall"),
     ("ablations", "(ours) compression sweep / strict semantics / fold"),
-    ("kernels", "(ours) sketch kernel micro + traffic model"),
     ("fused_store", "(ours) fused vs composed update_read steps/sec"),
     ("obs_overhead", "(ours) telemetry on/off steps/s A-B"),
     ("serving", "(ours) SLO traffic replay: dense vs count-min adaptation"),

@@ -73,26 +73,26 @@ class SketchHParams:
     ``dense_chunk``: the dense-gradient path processes the n rows in
     chunks of this size inside one ``lax.scan`` — query(pre-step sketch),
     delta, scatter, and the direction row all fused per chunk, the
-    XLA mirror of the Pallas ``cs_adam_fused`` kernel.  Peak temp drops
+    XLA mirror of the Pallas ``cs_ema_tiled`` kernel.  Peak temp drops
     from O(depth·n·d) to O(depth·chunk·d).  0 disables chunking (the
     reference unchunked path; bit-identical results).
 
-    ``lazy``: rows whose gradient is entirely zero get NO parameter
-    update (and no sketch write) — the paper's per-item algorithm only
-    touches active features.  Without it, a zero-grad row's update is
+    Rows whose gradient is entirely zero always get NO parameter update
+    (and no sketch write) — the paper's per-item algorithm only touches
+    active features.  Without that, a zero-grad row's update is
     median-noise / sqrt(min-estimate ≈ 0), which diverges (observed:
     tests/test_optimizers.py::TestConvergence).
 
     ``backend``: which kernel backend sketch ops run on — a name
-    registered in ``repro.kernels.registry`` ('ref' | 'xla' | 'stream' |
-    'tiled' | 'interpret') or 'auto' for the per-host best (tiled on
-    TPU, xla elsewhere).  Routes BOTH the sparse-rows fast path
-    (DESIGN.md §10) and the dense-path fused ``update_read`` of every
-    sketch-backed store these hparams derive (the stores are created
-    with ``backend=`` — DESIGN.md §14).  None keeps the sparse path on
+    registered in ``repro.kernels.registry`` ('ref' | 'xla' | 'tiled' |
+    'interpret') or 'auto' for the per-host best (tiled on TPU, xla
+    elsewhere).  Routes BOTH the sparse-rows fast path (DESIGN.md §10)
+    and the dense-path fused ``update_read`` of every sketch-backed
+    store these hparams derive (the stores are created with
+    ``backend=`` — DESIGN.md §14).  None keeps the sparse path on
     'auto' and the dense path on the composed fallback (bit-identical
-    legacy numerics); 'stream' exists only for the sparse pair op, so
-    the dense path treats it as None.
+    legacy numerics).  Any other name raises ``KeyError`` on either
+    path when the step traces.
 
     ``overrides``: per-path (depth, width) assignments.  Legacy hook; new
     code pins per-leaf specs through a ``StoreTree`` instead (the
@@ -109,7 +109,6 @@ class SketchHParams:
     identity: bool = False    # exact-table test mode
     strict_paper: bool = False  # 3-pass query→update→query semantics
     dense_chunk: int = 8192
-    lazy: bool = True
     backend: Optional[str] = None
     dtype: str = "float32"
     overrides: Tuple[Tuple[str, Tuple[int, int]], ...] = ()
@@ -163,19 +162,6 @@ def _with_lr(rule: Transform, lr: Schedule) -> Transform:
     return Transform(init, update)
 
 
-def _update_read_backend(backend: Optional[str]) -> Optional[str]:
-    """``hparams.backend`` filtered for the dense-path fused op: names
-    registered for ('sketch', 'update_read') (or 'auto') pass through;
-    sparse-rows-only backends ('stream') map to None — the composed
-    fallback — so one knob can drive both hot paths without the dense
-    one crashing on a pair-op-only name."""
-    if backend is None or backend == "auto":
-        return backend
-    from repro.kernels import registry  # deferred: kernels import jax deps
-    return backend if backend in registry.backends("sketch", "update_read") \
-        else None
-
-
 def stores_from_policy(policy: PolicyFn = nothing_policy, *,
                        rank1_policy: PolicyFn = nothing_policy,
                        hparams: SketchHParams = SketchHParams(),
@@ -191,11 +177,9 @@ def stores_from_policy(policy: PolicyFn = nothing_policy, *,
     ``rule`` picks the slot layout: 'adam' fills (m, v); 'momentum' a
     signed sketch in the m slot only; 'adagrad' a count-min in the v
     slot only.  ``hparams.backend`` rides onto every sketch-backed store
-    (its fused ``update_read`` backend — DESIGN.md §14); names that only
-    exist for the sparse-rows pair op (e.g. 'stream') leave the dense
-    path on the composed fallback instead of crashing it."""
+    unchanged (its fused ``update_read`` backend — DESIGN.md §14)."""
     track = track_first_moment
-    backend = _update_read_backend(hparams.backend)
+    backend = hparams.backend
 
     def _dense_m():
         return DenseStore() if track else None
@@ -244,14 +228,14 @@ def stores_from_policy(policy: PolicyFn = nothing_policy, *,
 
 def adam_from_stores(lr: Schedule, stores: StoreTree, *, b1: float = 0.9,
                      b2: float = 0.999, eps: float = 1e-8,
-                     dense_chunk: int = 8192, lazy: bool = True,
+                     dense_chunk: int = 8192,
                      strict_paper: bool = False) -> Transform:
     """``chain(scale_by_adam(stores=...), scale_by_lr(lr))`` in the legacy
     ``{"step", "m", "v"}`` state layout — what the memory-budget planner
     executes (``plan.Plan.make_optimizer``) and what the benchmarks'
     ``--store`` axis drives."""
     return _with_lr(T.scale_by_adam(b1=b1, b2=b2, eps=eps, stores=stores,
-                                    dense_chunk=dense_chunk, lazy=lazy,
+                                    dense_chunk=dense_chunk,
                                     strict_paper=strict_paper), lr)
 
 
@@ -299,7 +283,7 @@ def countsketch_momentum(lr: Schedule, gamma: float = 0.9, *,
     stores = stores_from_policy(policy, hparams=hparams, rule="momentum")
     return _with_lr(T.scale_by_momentum(
         gamma, stores=stores, dense_chunk=hparams.dense_chunk,
-        lazy=hparams.lazy, strict_paper=hparams.strict_paper), lr)
+        strict_paper=hparams.strict_paper), lr)
 
 
 def countsketch_adagrad(lr: Schedule, eps: float = 1e-10, *,
@@ -342,7 +326,6 @@ def countsketch_adam(lr: Schedule, b1: float = 0.9, b2: float = 0.999,
         sketch_first_moment=sketch_first_moment)
     return adam_from_stores(lr, stores, b1=b1, b2=b2, eps=eps,
                             dense_chunk=hparams.dense_chunk,
-                            lazy=hparams.lazy,
                             strict_paper=hparams.strict_paper)
 
 
@@ -359,7 +342,7 @@ def countsketch_rmsprop(lr: Schedule, b2: float = 0.999, eps: float = 1e-8, *,
                                 sketch_first_moment=False)
     return _with_lr(T.scale_by_rmsprop(
         b2=b2, eps=eps, stores=stores, dense_chunk=hparams.dense_chunk,
-        lazy=hparams.lazy, strict_paper=hparams.strict_paper), lr)
+        strict_paper=hparams.strict_paper), lr)
 
 
 # ---------------------------------------------------------------------------
@@ -381,11 +364,11 @@ def adam_sparse_rows(spec_m: Optional[cs.SketchSpec], spec_v: cs.SketchSpec,
     the β₁=0 variant.
 
     ``backend`` routes the step through the kernel registry in
-    ``repro.kernels`` ('ref' | 'xla' | 'stream' | 'tiled' | 'interpret',
-    or 'auto' for the per-host best).  Registry backends handle duplicate ids
-    themselves (the tiled backend dedups + segment-sums them; the
-    streaming ones compose them through the EMA) and return row updates
-    such that ``params.at[ids].add(upd)`` is the correct application.
+    ``repro.kernels`` ('ref' | 'xla' | 'tiled' | 'interpret', or 'auto'
+    for the per-host best).  Registry backends handle duplicate ids
+    themselves (xla and tiled dedup + segment-sum them; ref composes them
+    through the EMA item by item) and return row updates such that
+    ``params.at[ids].add(upd)`` is the correct application.
 
     ``backend=None`` keeps the in-graph XLA batch path below, where
     ``ids`` must be de-duplicated by the caller (use

@@ -172,7 +172,8 @@ def _pick_chunk(n: int, target: int) -> int:
 
 
 def _row_active(g):
-    """1.0 for rows with any non-zero gradient, else 0.0 (lazy updates)."""
+    """1.0 for rows with any non-zero gradient, else 0.0: the row-active
+    mask every sketched path applies."""
     return jnp.any(g != 0, axis=-1, keepdims=True).astype(jnp.float32)
 
 
@@ -211,7 +212,7 @@ def scale_by_momentum(gamma: float = 0.9, *,
                       stores: Optional[StoreTree] = None,
                       m_store: Optional[AuxStore] = None,
                       where=None,
-                      dense_chunk: int = 8192, lazy: bool = True,
+                      dense_chunk: int = 8192,
                       strict_paper: bool = False) -> Transform:
     """Polyak momentum ``m ← γm + g``; emits ``m`` (the direction).  The
     per-leaf m store is the ``StoreTree``'s m slot: ``DenseStore`` runs
@@ -245,23 +246,20 @@ def scale_by_momentum(gamma: float = 0.9, *,
                 return m_new, m_new
             if _fused(ms) and not strict_paper:
                 # one fused kernel over the whole table (DESIGN.md §14)
-                act = _row_active(g) if lazy else 1.0
+                act = _row_active(g)
                 M_out, m_est = ms.update_read(M, g, gamma, scale=1.0,
-                                              mask=act if lazy else None,
-                                              step=step)
+                                              mask=act, step=step)
                 return M_out, act * m_est
             if dense_chunk and not strict_paper:
                 def chunk_step(carry, ids, gc):
-                    act = _row_active(gc) if lazy else 1.0
+                    act = _row_active(gc)
                     carry, m_est = ms.update_read(
                         carry, gc, gamma, scale=1.0, rows=ids,
-                        mask=act if lazy else None, read_state=M,
-                        step=step)
+                        mask=act, read_state=M, step=step)
                     return carry, act * m_est
                 return _sketched_rows_scan(g, M, chunk_step, dense_chunk)
-            act = _row_active(g) if lazy else 1.0
-            M_out, m_est = ms.update_read(M, g, gamma, scale=1.0,
-                                          mask=act if lazy else None,
+            act = _row_active(g)
+            M_out, m_est = ms.update_read(M, g, gamma, scale=1.0, mask=act,
                                           strict=strict_paper, step=step)
             return M_out, act * m_est
 
@@ -353,7 +351,7 @@ def scale_by_adam(b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8, *,
                   stores: Optional[StoreTree] = None,
                   m_store: Any = _UNSET, v_store: Any = _UNSET,
                   where=None,
-                  dense_chunk: int = 8192, lazy: bool = True,
+                  dense_chunk: int = 8192,
                   strict_paper: bool = False) -> Transform:
     """Adam whose moments live wherever the ``StoreTree`` says: per leaf,
     the 1st moment in a ``DenseStore``, a ``CountSketchStore`` (signed,
@@ -364,9 +362,11 @@ def scale_by_adam(b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8, *,
 
     ``m_store``/``v_store`` + ``where`` is sugar for a two-level
     ``StoreTree``: selected leaves get those stores, the rest stay dense
-    (pass ``m_store=None`` for the β₁=0 layout).  ``dense_chunk``,
-    ``lazy`` and ``strict_paper`` are the execution knobs of the old
-    ``SketchHParams``, unchanged in meaning."""
+    (pass ``m_store=None`` for the β₁=0 layout).  ``dense_chunk`` and
+    ``strict_paper`` are the execution knobs of the old ``SketchHParams``,
+    unchanged in meaning.  Rows whose gradient is entirely zero get no
+    update and no sketch write: the paper's per-item algorithm touches
+    only active features."""
     if stores is None:
         stores = StoreTree.select(
             m=DenseStore() if m_store is _UNSET else m_store,
@@ -452,17 +452,16 @@ def scale_by_adam(b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8, *,
                 # the single-pass hot path (DESIGN.md §14).  The §4
                 # cleaning hook fired above on V_in, exactly as on the
                 # composed paths.
-                act = _row_active(g) if lazy else 1.0
-                mask = act if lazy else None
+                act = _row_active(g)
                 if sketched_m:
-                    M_out, m_est = ms.update_read(M, g, b1, mask=mask,
+                    M_out, m_est = ms.update_read(M, g, b1, mask=act,
                                                   step=step)
                     mhat = m_est / bc1
                 elif ms is not None:
                     mhat = mhat_rows
                 else:
                     mhat = g
-                V_out, v_est = vs.update_read(V_in, g * g, b2, mask=mask,
+                V_out, v_est = vs.update_read(V_in, g * g, b2, mask=act,
                                               step=step)
                 vh = jnp.maximum(v_est, 0.0) / bc2
                 return M_out, V_out, act * mhat / (jnp.sqrt(vh) + eps)
@@ -473,11 +472,10 @@ def scale_by_adam(b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8, *,
                 # over the PRE-step sketches via ``read_state``
                 # (canonical batch semantics).
                 def chunk_step(carry, ids, gc, *mh_c):
-                    act = _row_active(gc) if lazy else 1.0
-                    mask = act if lazy else None
+                    act = _row_active(gc)
                     if sketched_m:
                         carry["M"], m_est = ms.update_read(
-                            carry["M"], gc, b1, rows=ids, mask=mask,
+                            carry["M"], gc, b1, rows=ids, mask=act,
                             read_state=M, step=step)
                         mh = m_est / bc1
                     elif ms is not None:
@@ -485,7 +483,7 @@ def scale_by_adam(b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8, *,
                     else:
                         mh = gc
                     carry["V"], v_est = vs.update_read(
-                        carry["V"], gc * gc, b2, rows=ids, mask=mask,
+                        carry["V"], gc * gc, b2, rows=ids, mask=act,
                         read_state=V_in, step=step)
                     vh = jnp.maximum(v_est, 0.0) / bc2
                     return carry, act * mh / (jnp.sqrt(vh) + eps)
@@ -500,17 +498,16 @@ def scale_by_adam(b1: float = 0.9, b2: float = 0.999, eps: float = 1e-8, *,
                 return M_out, carry["V"], upd
 
             # reference unchunked path (also the strict-paper 3-pass mode)
-            act = _row_active(g) if lazy else 1.0
-            mask = act if lazy else None
+            act = _row_active(g)
             if sketched_m:
-                M_out, m_est = ms.update_read(M, g, b1, mask=mask,
+                M_out, m_est = ms.update_read(M, g, b1, mask=act,
                                               strict=strict_paper, step=step)
                 mhat = m_est / bc1
             elif ms is not None:
                 mhat = mhat_rows
             else:
                 mhat = g
-            V_out, v_est = vs.update_read(V_in, g * g, b2, mask=mask,
+            V_out, v_est = vs.update_read(V_in, g * g, b2, mask=act,
                                           strict=strict_paper, step=step)
             v_new = jnp.maximum(v_est, 0.0)
             upd = act * mhat / (jnp.sqrt(v_new / bc2) + eps)
@@ -663,7 +660,7 @@ def scale_by_adam_rows_sharded(b1: float = 0.9, b2: float = 0.999,
 def scale_by_rmsprop(b2: float = 0.999, eps: float = 1e-8, *,
                      stores: Optional[StoreTree] = None,
                      v_store: Any = _UNSET, where=None,
-                     dense_chunk: int = 8192, lazy: bool = True,
+                     dense_chunk: int = 8192,
                      strict_paper: bool = False) -> Transform:
     """The β₁=0 rule of Theorem 5.1 (Count-Min Adam without the 1st
     moment): ``scale_by_adam`` with every m slot forced to ``None`` —
@@ -674,7 +671,7 @@ def scale_by_rmsprop(b2: float = 0.999, eps: float = 1e-8, *,
             where=where, default_m=None)
     return scale_by_adam(b1=0.0, b2=b2, eps=eps,
                          stores=stores.without_first_moment(),
-                         dense_chunk=dense_chunk, lazy=lazy,
+                         dense_chunk=dense_chunk,
                          strict_paper=strict_paper)
 
 
@@ -695,11 +692,12 @@ def scale_by_adam_rows(b1: float = 0.9, b2: float = 0.999,
     ``m_store`` (``CountSketchStore`` or None for β₁=0) and ``v_store``
     (``CountMinStore``, cleaning hook honored) must be bound (explicit
     ``spec``); the step routes their specs through the kernel-backend
-    registry (``repro.kernels``: 'ref' | 'xla' | 'stream' | 'tiled' |
-    'interpret', None/'auto' = per-host best), which handles duplicate
-    ids.  Emits ``{"ids", "rows": direction}`` with the direction
-    *unscaled* — compose with ``scale_by_lr`` (which leaves the integer
-    ``ids`` leaf untouched) and apply via ``apply_sparse_updates``.
+    registry (``repro.kernels``: 'ref' | 'xla' | 'tiled' | 'interpret',
+    None/'auto' = per-host best; any other name raises ``KeyError``),
+    which handles duplicate ids.  Emits ``{"ids", "rows": direction}``
+    with the direction *unscaled* — compose with ``scale_by_lr`` (which
+    leaves the integer ``ids`` leaf untouched) and apply via
+    ``apply_sparse_updates``.
 
     ``dir_clip``: the per-coordinate trust clamp of the DP path
     (``sketched_reduce.dp_adam_rows``), for the same reason: the numerator

@@ -1,13 +1,9 @@
-"""Elastic scaling + straggler mitigation (host-side control plane).
+"""Elastic scaling (host-side control plane).
 
-JAX multi-host steps are synchronous SPMD programs: a straggling or dead
-host stalls the whole pod.  Production mitigation is therefore a control
-loop *around* the compiled step:
+JAX multi-host steps are synchronous SPMD programs: a dead host stalls
+the whole pod.  Recovery is therefore a control loop *around* the
+compiled step:
 
-  * ``StragglerMonitor`` — EWMA per-host step times; flags hosts whose
-    time exceeds ``threshold ×`` the fleet median.  The launcher uses the
-    flag to (a) emit an alert, (b) schedule the host for exclusion at the
-    next checkpoint boundary (TPU pods cannot drop a chip mid-program).
   * ``ElasticPlan`` — given the surviving host/chip count, picks the new
     mesh (largest power-of-two data axis that fits), and decides whether
     the count-sketch optimizer state must FOLD (halve width — Hokusai,
@@ -24,42 +20,7 @@ re-layout is ordinary checkpoint restore with new shardings
 from __future__ import annotations
 
 import dataclasses
-import math
-import time
-from typing import Callable, Dict, List, Optional, Tuple
-
-
-@dataclasses.dataclass
-class StragglerMonitor:
-    """EWMA step-time tracker with median-relative flagging."""
-
-    threshold: float = 1.5      # flag hosts slower than 1.5× fleet median
-    alpha: float = 0.2          # EWMA smoothing
-    min_samples: int = 5
-    _ewma: Dict[int, float] = dataclasses.field(default_factory=dict)
-    _count: Dict[int, int] = dataclasses.field(default_factory=dict)
-
-    def record(self, host: int, step_time: float) -> None:
-        prev = self._ewma.get(host)
-        self._ewma[host] = (step_time if prev is None
-                            else self.alpha * step_time + (1 - self.alpha) * prev)
-        self._count[host] = self._count.get(host, 0) + 1
-
-    def median(self) -> Optional[float]:
-        vals = sorted(self._ewma.values())
-        if not vals:
-            return None
-        n = len(vals)
-        return vals[n // 2] if n % 2 else 0.5 * (vals[n // 2 - 1] + vals[n // 2])
-
-    def stragglers(self) -> List[int]:
-        med = self.median()
-        if med is None or med == 0.0:
-            return []
-        return sorted(
-            h for h, t in self._ewma.items()
-            if self._count.get(h, 0) >= self.min_samples
-            and t > self.threshold * med)
+from typing import Callable, Optional
 
 
 def largest_pow2_leq(n: int) -> int:

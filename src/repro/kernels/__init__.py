@@ -1,9 +1,5 @@
 """Pallas-TPU kernels for the count-sketch hot path.
 
-  cs_query.py      — scalar-prefetch gather + median/min reduce (batch QUERY)
-  cs_update.py     — bucket-sorted sequential-grid scatter-accumulate (batch UPDATE)
-  cs_adam.py       — fused STREAMING Adam: one item per grid step, exact
-                     per-item (paper) semantics
   cs_adam_tiled.py — fused TILED Adam: TILE deduplicated rows per grid step,
                      double-buffered grad/update pipeline (DESIGN.md §10)
   cs_ema_tiled.py  — fused TILED update_read: one moment's query→Δ→scatter
@@ -14,8 +10,9 @@
   row_groups.py    — the aligned row-group DMA + one-hot select/place
                      machinery both tiled kernels share, and the rules
                      for what the TPU compiler accepts
-  ops.py           — jit'd wrappers, one implementation per backend name
-  ref.py           — pure-jnp oracles (bit-exact semantics definitions)
+  ops.py           — the registered implementations, one per backend name
+  ref.py           — pure-jnp oracles: the 'ref' pair backend and the
+                     tiled kernel's per-tile reference in the tests
   registry.py      — the shared (store kind, op) → {backend: fn} registry
 
 Backend registry
@@ -32,8 +29,6 @@ manifests), ``launch/train.py --store-backend``, and the benchmarks.
   xla        dedup pre-pass + the vectorized jnp batch step — no Pallas;
              same semantics as ``tiled`` with one whole-batch tile (the
              default off-TPU)
-  stream     ``cs_adam_fused`` Pallas kernel — one item per sequential grid
-             step; exact per-item semantics, throughput-bound
   tiled      dedup pre-pass + ``cs_adam_tiled`` — TILE rows per grid step;
              identical to ``ref`` on collision-free batches, within
              median/min-noise tolerance otherwise (the TPU fast path:
@@ -63,43 +58,26 @@ into / gather out of one shard's (depth, local_width, dim) slab.
              slab ops run under shard_map where Pallas grids don't
              compose yet, so 'auto' resolves to 'xla' on every host)
 
-'stream' exists only for the pair op (per-item ordering is its point);
-``update_read`` is defined batch-wise.  ``resolve_backend(None|'auto')``
-picks ``tiled`` on TPU and ``xla`` elsewhere; given the op's sketch specs
-(as ``adam_rows``/``update_read`` do) it picks ``xla`` for a sketch the
+Each op runs one implementation per platform — ``tiled`` on a TPU,
+``xla`` elsewhere (``xla`` everywhere for the slab ops) — besides the
+``ref`` oracle and, for the two kernels, the ``interpret`` backend the
+tests need.  ``registry.resolve(kind, op, None|'auto')`` picks
+``tiled`` on TPU and ``xla`` elsewhere; given the op's sketch specs (as
+``adam_rows``/``update_read`` do) it picks ``xla`` for a sketch the
 kernel refuses (int8 cells, dim not a multiple of 128, bf16 in the pair
-op), and an explicit backend name that refuses a sketch raises.  New
-backends (e.g. a GPU port) attach via ``registry.register``.
+op), and an explicit backend name that refuses a sketch — or that is not
+registered — raises.  New backends (e.g. a GPU port) attach via
+``registry.register``.
 """
 from __future__ import annotations
 
 import functools
-from typing import Callable, Optional, Tuple
+from typing import Optional
 
 import jax.numpy as jnp
 
 from repro.kernels import dedup, ops, ref, registry  # noqa: F401
 from repro.kernels import row_groups as rg
-
-
-def register_backend(name: str, fn: Callable) -> None:
-    """Register (or override) a sparse-rows CS-Adam ('pair', 'adam_rows')
-    backend — the PR-1 flat API, kept for compatibility."""
-    registry.register("pair", "adam_rows", name, fn)
-
-
-def backends() -> Tuple[str, ...]:
-    """Registered sparse-rows backend names, registration order."""
-    return registry.backends("pair", "adam_rows")
-
-
-def resolve_backend(name: Optional[str] = None) -> str:
-    """Map None/'auto' to the best sparse-rows backend for this host;
-    validate names."""
-    try:
-        return registry.resolve("pair", "adam_rows", name)
-    except KeyError as e:
-        raise KeyError(str(e)) from None
 
 
 def adam_rows(spec_m, spec_v, M, V, ids, g, step, *,
@@ -195,10 +173,8 @@ def _ema_tiled(spec) -> Optional[str]:
     return rg.tiled_refusal(spec.dim, spec.width, spec.dtype)
 
 
-register_backend("ref", ops.adam_rows_ref)
-register_backend("xla", ops.adam_rows_xla)
-registry.register("pair", "adam_rows", "stream", ops.adam_rows_stream,
-                  refusal=_f32_only)
+registry.register("pair", "adam_rows", "ref", ops.adam_rows_ref)
+registry.register("pair", "adam_rows", "xla", ops.adam_rows_xla)
 registry.register("pair", "adam_rows", "tiled", ops.adam_rows_tiled,
                   refusal=_pair_tiled)
 registry.register("pair", "adam_rows", "interpret",

@@ -1,20 +1,25 @@
 """Tiled batch-parallel CS-Adam — ``TILE`` collision-free rows per grid step.
 
-The streaming kernel (``cs_adam.py``) advances ONE item per grid step so
-that duplicate ids compose through the EMA exactly as in the paper's
-per-item algorithm.  After the dedup pre-pass (``dedup.py``) the batch is
+The paper's Algorithm 4 advances one item at a time, so that duplicate
+ids compose through the EMA in order (``ref.adam_fused_ref`` is that
+per-item oracle).  After the dedup pre-pass (``dedup.py``) the batch is
 collision-free in id-space, and the per-item ordering no longer matters:
 the batched step over the tile is algebraically identical for ids that
-never share a sketch bucket (DESIGN.md §10).  That removes the throughput
-ceiling:
+never share a sketch bucket (DESIGN.md §10).  So the kernel runs the
+whole Adam row update
+
+    m_old = median_j  s_j(i)·M[j, h_j(i)];  Δm = (1−β₁)(g_i − m_old)
+    v_old = min_j  V[j, h'_j(i)];            Δv = (1−β₂)(g_i² − v_old)
+    upd_i = −η·(m_old+Δm)/bc₁ / (√((v_old+Δv)⁺/bc₂) + ε)
+
+over ``TILE`` rows at once:
 
   * the gradient rows and the parameter-update rows move through the
     normal double-buffered BlockSpec pipeline, ``TILE`` rows per step —
     the compiler overlaps the step ``t+1`` fetch with step ``t`` compute;
   * the sketches stay in ``pl.ANY`` (HBM) and each step issues all
     ``depth × TILE`` DMAs of the aligned row groups that hold the
-    addressed buckets at once (overlapped, one wait; ``row_groups.py``),
-    instead of the streaming kernel's per-item round trip;
+    addressed buckets at once (overlapped, one wait; ``row_groups.py``);
   * the row update itself is vectorized over the (TILE, d) block on the
     VPU, with the depth-way median/min unchanged.
 
@@ -28,10 +33,16 @@ segment-sum into the group placement matmul of ``row_groups.Groups``:
 Entries whose buckets share a row group then write back *identical*
 fully-accumulated groups, so any DMA completion order is correct.  Estimates still read the
 pre-tile sketch — batch semantics inside a tile, streaming semantics
-across tiles (tile t+1 observes tile t's writes through the sequential
-TPU grid; see cs_update.py for the same race-freedom argument), and
-batches longer than one call's SMEM address budget run as consecutive
-calls with the same streaming order.
+across tiles, and batches longer than one call's SMEM address budget run
+as consecutive calls with the same streaming order.
+
+Why this is race-free without atomics (a GPU port would scatter-add
+atomically; DESIGN.md §3): the TPU grid runs its steps in order on one
+core, and each step waits on its own write-back DMAs before it ends, so
+tile t+1's gather DMAs are issued only after tile t's writes have landed
+in HBM.  Within a tile, the one read-modify-write hazard — two entries
+writing the same row group — is closed by the identical-groups argument
+above.
 
 Rows past ``n_valid`` (dedup/tile padding) contribute exactly zero to
 every sketch bucket and emit zero update rows.  A tile that starts at or
@@ -196,7 +207,9 @@ def cs_adam_tiled(M: Optional[jnp.ndarray], V: jnp.ndarray,
                   ) -> Tuple[Optional[jnp.ndarray], jnp.ndarray, jnp.ndarray]:
     """Batch-parallel CS-Adam over ``k`` COLLISION-FREE (deduplicated) rows.
 
-    Same contract as ``cs_adam.cs_adam_fused`` plus:
+    Returns ``(M', V', param_update_rows)``.  ``bm``/``sm``: (depth, k)
+    buckets and signs for ``M``; ``bv``: (depth, k) buckets for ``V``;
+    ``g``: the (k, d) gradient rows.  Scalars as in ``ref.adam_fused_ref``.
 
     n_valid: rows at positions >= n_valid are padding — their gradients are
         ignored and their update rows are zero.  Defaults to ``k``.

@@ -1,10 +1,10 @@
-"""Jit'd wrappers for the sketch kernels.
+"""The registry's implementations, one function per (op, backend).
 
-Each function runs exactly the implementation its name (or registry
-backend) says: a Pallas kernel compiled for the TPU, the same kernel under
-the Pallas interpreter only when asked for by name ('interpret' backend,
-``interpret=True``, ``force='interpret'``), or a pure-jnp form.  Which
-backend serves a sketch is decided once, by ``registry.resolve``.
+Each function runs exactly the implementation its name says: a Pallas
+kernel compiled for the TPU, the same kernel under the Pallas interpreter
+only when asked for by name ('interpret' backend, ``interpret=True``), or
+a pure-jnp form.  Which backend serves a sketch is decided once, by
+``registry.resolve``.
 """
 from __future__ import annotations
 
@@ -19,23 +19,8 @@ from repro.core import sketch as cs
 from repro.core.sketch import SketchSpec
 from repro.kernels import dedup as dd
 from repro.kernels import ref
-from repro.kernels.cs_adam import cs_adam_fused
 from repro.kernels.cs_adam_tiled import DEFAULT_TILE, cs_adam_tiled
 from repro.kernels.cs_ema_tiled import DEFAULT_TILE as EMA_TILE, cs_ema_tiled
-from repro.kernels.cs_query import cs_query
-from repro.kernels.cs_update import cs_update
-
-
-def _use_kernel(force: Optional[str]) -> Tuple[bool, bool]:
-    """(run the Pallas kernel, under the interpreter) for ``force``:
-    None = the compiled kernel on a TPU host and the jnp oracle elsewhere,
-    'pallas' = the compiled kernel, 'interpret' = the interpreter."""
-    if force not in (None, "pallas", "interpret"):
-        raise ValueError(f"force={force!r}: expected None, 'pallas' or "
-                         "'interpret'")
-    if force is None:
-        return jax.default_backend() == "tpu", False
-    return True, force == "interpret"
 
 
 def _lowp(spec: SketchSpec) -> bool:
@@ -48,35 +33,6 @@ def _addressing(spec: SketchSpec, ids: jnp.ndarray):
     buckets = fam.bucket(ids)
     signs = fam.sign(ids) if spec.signed else None
     return buckets, signs
-
-
-def sketch_query(spec: SketchSpec, S: jnp.ndarray, ids: jnp.ndarray, *,
-                 force: Optional[str] = None) -> jnp.ndarray:
-    """QUERY rows ``ids``; Pallas gather kernel on TPU, jnp gather off-TPU
-    (``force``: see ``_use_kernel``)."""
-    if _lowp(spec):
-        # low-precision cells: the core gather dequantizes in-register
-        return cs.query(spec, S, ids)
-    buckets, signs = _addressing(spec, ids)
-    kernel, interpret = _use_kernel(force)
-    if kernel:
-        return cs_query(S, buckets, signs, interpret=interpret)
-    return ref.cs_query_ref(S, buckets, signs)
-
-
-def sketch_update(spec: SketchSpec, S: jnp.ndarray, ids: jnp.ndarray,
-                  delta: jnp.ndarray, *,
-                  force: Optional[str] = None) -> jnp.ndarray:
-    """UPDATE rows ``ids`` with ``delta``; sorted-scatter kernel on TPU
-    (``force``: see ``_use_kernel``)."""
-    if _lowp(spec):
-        # low-precision cells: stochastic-rounding write in the core
-        return cs.update(spec, S, ids, delta)
-    buckets, signs = _addressing(spec, ids)
-    kernel, interpret = _use_kernel(force)
-    if kernel:
-        return cs_update(S, buckets, signs, delta, interpret=interpret)
-    return ref.cs_update_ref(S, buckets, signs, delta)
 
 
 def _adam_hypers(step: jnp.ndarray, lr, b1: float, b2: float):
@@ -113,21 +69,6 @@ def adam_rows_ref(spec_m: Optional[SketchSpec], spec_v: SketchSpec,
     eta, bc1, bc2 = _adam_hypers(step, lr, b1, b2)
     return ref.adam_fused_ref(M, V, bm, sm, bv, g, lr=eta, b1=b1, b2=b2,
                               eps=eps, bc1=bc1, bc2=bc2)
-
-
-def adam_rows_stream(spec_m: Optional[SketchSpec], spec_v: SketchSpec,
-                     M: Optional[jnp.ndarray], V: jnp.ndarray,
-                     ids: jnp.ndarray, g: jnp.ndarray, step: jnp.ndarray, *,
-                     lr, b1: float = 0.9, b2: float = 0.999,
-                     eps: float = 1e-8, interpret: bool = False
-                     ) -> Tuple[Optional[jnp.ndarray], jnp.ndarray, jnp.ndarray]:
-    """'stream' backend: one-item-per-grid-step Pallas kernel — exact
-    per-item semantics, sequential over the batch.  float32 cells only
-    (the registry refuses others)."""
-    bm, sm, bv = _adam_addressing(spec_m, spec_v, ids)
-    eta, bc1, bc2 = _adam_hypers(step, lr, b1, b2)
-    return cs_adam_fused(M, V, bm, sm, bv, g, lr=eta, b1=b1, b2=b2,
-                         eps=eps, bc1=bc1, bc2=bc2, interpret=interpret)
 
 
 def adam_rows_xla(spec_m: Optional[SketchSpec], spec_v: SketchSpec,

@@ -5,12 +5,12 @@ All functions take pre-computed hash ``buckets``/``signs`` (from
 bit-identical addressing.  Two semantics exist (see core/sketch.py):
 
   * batch     — query sees the pre-step sketch; scatter-adds accumulate.
-                (cs_query / cs_update kernels)
+                ``cs_query_ref`` / ``cs_update_ref``: the tiled kernels'
+                semantics within one tile.
   * streaming — rows are processed one at a time, later rows see earlier
                 rows' updates.  This is the paper's exact per-item
-                algorithm; the fused Adam kernel implements it in one HBM
-                pass, and ``adam_fused_ref`` reproduces it with a
-                ``lax.scan``.
+                algorithm; ``adam_fused_ref`` reproduces it with a
+                ``lax.scan`` (the 'ref' pair backend).
 """
 from __future__ import annotations
 

@@ -1,29 +1,21 @@
 """Shared kernel-backend registry: (store kind, op) → {backend: fn}.
 
-PR 1 introduced interchangeable implementations ("backends") for the
-sparse-rows CS-Adam step, keyed by name in ``kernels/__init__.py``.  The
-fused-store refactor (DESIGN.md §14) adds a second kernelized op — the
-dense-path ``update_read`` of the ``AuxStore`` protocol — so the flat
-name → fn table becomes a two-level registry dispatching on
+Interchangeable implementations ("backends") of each kernelized op are
+keyed by name and dispatched on
 
     kind    which store owns the op: 'sketch' (signed Count-Sketch),
             'countmin' (unsigned Count-Min), or 'pair' (ops spanning an
             (m, v) store pair, e.g. the fused sparse-rows Adam step);
-    op      the protocol operation ('adam_rows' | 'update_read');
-    backend the implementation name ('ref' | 'xla' | 'stream' | 'tiled'
-            | 'interpret' | ...), with None/'auto' resolved per platform
+    op      the protocol operation ('adam_rows' | 'update_read' |
+            'update_slab' | 'gather_slab');
+    backend the implementation name ('ref' | 'xla' | 'tiled' |
+            'interpret'), with None/'auto' resolved per platform
             (Pallas 'tiled' on TPU, vectorized 'xla' elsewhere).
 
-Not every (kind, op) offers every backend — 'stream' (one item per grid
-step) exists only for the sparse-rows pair op, where exact per-item
-ordering matters; the dense ``update_read`` is defined batch-wise and
-registers ref | xla | tiled | interpret.  ``backends(kind, op)``
-enumerates what is actually available; new implementations (e.g. a GPU
-port) attach via ``register``.
-
-``kernels/__init__.py`` keeps the PR-1 flat API (``register_backend`` /
-``backends()`` / ``resolve_backend`` / ``adam_rows``) as thin wrappers
-over the ('pair', 'adam_rows') row of this registry.
+The ``adam_rows`` and ``update_read`` rows register ref | xla | tiled |
+interpret; the slab ops register ref | xla.  ``backends(kind, op)``
+enumerates what is available; new implementations (e.g. a GPU port)
+attach via ``register``.  An unregistered name raises ``KeyError``.
 
 A backend may register a ``refusal(spec) -> Optional[str]``: the reason
 it cannot run a sketch (the Pallas kernels need whole 128-lane rows and

@@ -631,8 +631,9 @@ def run(argv: Optional[Sequence[str]] = None) -> RunReport:
                     help="kernel backend for the sketch hot paths: the "
                          "fused dense-path update_read AND the sparse-rows "
                          "step ('ref' | 'xla' | 'tiled' | 'interpret' | "
-                         "'auto'; DESIGN.md §14).  Empty = composed "
-                         "fallback on the dense path.  An execution knob "
+                         "'auto'; any other name raises; DESIGN.md §14).  "
+                         "Empty = composed fallback on the dense path.  "
+                         "An execution knob "
                          "only — overrides whatever backend a recorded "
                          "plan/manifest carries without touching state "
                          "layout, so restores stay valid")

@@ -212,7 +212,7 @@ class Plan:
                        backend: Optional[str] = None) -> Transform:
         """``adam_from_stores(lr, self.store_tree())`` in the legacy state
         layout.  ``base_hparams`` keeps the orthogonal execution knobs
-        (dense_chunk, lazy, strict_paper); ``backend`` overrides the
+        (dense_chunk, strict_paper); ``backend`` overrides the
         plan's own ``backend`` for this optimizer — every sketched leaf
         then runs its fused ``update_read`` through that kernel backend
         (DESIGN.md §14) instead of the composed chunked scan."""
@@ -221,8 +221,7 @@ class Plan:
         return adam_from_stores(
             lr, plan.store_tree(cleaning=cleaning),
             b1=(0.0 if not self.track_first_moment else b1), b2=b2, eps=eps,
-            dense_chunk=hp.dense_chunk, lazy=hp.lazy,
-            strict_paper=hp.strict_paper)
+            dense_chunk=hp.dense_chunk, strict_paper=hp.strict_paper)
 
     def specs(self) -> Dict[str, Dict[str, cs.SketchSpec]]:
         """Exact per-path SketchSpecs ({'m': ..., 'v': ...}) derived the

@@ -1,19 +1,18 @@
-"""Cross-backend parity: ref | stream | tiled | interpret (DESIGN.md §10).
+"""Cross-backend parity: ref | xla | tiled | interpret (DESIGN.md §10).
 
-Three tiers of agreement, from exact to statistical:
+Two tiers of agreement, from exact to statistical:
 
-  1. stream == ref everywhere (same per-item streaming semantics);
-  2. tiled == ref on COLLISION-FREE batches (the dedup-equivalence
+  1. tiled == ref on COLLISION-FREE batches (the dedup-equivalence
      argument: once ids are unique and no two ids share a sketch bucket,
      batch and per-item semantics coincide bit-for-bit);
-  3. on colliding batches tiled implements "batch within a tile,
+  2. on colliding batches tiled implements "batch within a tile,
      streaming across tiles" — asserted EXACTLY against a jnp oracle of
      that semantics, and within tolerance against ref (the residual is
      median/min estimator noise, quantified here with fixed seeds).
 
-Pallas backends run under the interpreter on CPU, named explicitly:
-the 'interpret' backend, or ``interpret=True`` on the stream kernel
-(kernel body in Python, BlockSpecs/DMAs as on TPU).  'tiled' itself only
+The Pallas kernel runs under the interpreter on CPU, named explicitly:
+the 'interpret' backend, or ``interpret=True`` on the kernel (kernel
+body in Python, BlockSpecs/DMAs as on TPU).  'tiled' itself only
 compiles for a TPU (tests/test_chip_compile.py).
 """
 import functools
@@ -25,7 +24,7 @@ import pytest
 
 import repro.kernels as K
 from repro.core import sketch as cs
-from repro.kernels import dedup as dd, ref
+from repro.kernels import dedup as dd, ref, registry
 
 
 LR = dict(lr=1e-2, b1=0.9, b2=0.999, eps=1e-8)
@@ -54,61 +53,20 @@ def _applied(n, d, ids, upd):
 
 def _run(backend, spec_m, spec_v, M, V, ids, g, step=2, **kw):
     kw = {**LR, **kw}
-    if backend == "stream":
-        # the stream kernel under the interpreter (no registry name for it)
-        from repro.kernels import ops
-        return ops.adam_rows_stream(
-            spec_m if M is not None else None, spec_v, M, V, ids, g,
-            jnp.asarray(step, jnp.int32), interpret=True, **kw)
     return K.adam_rows(spec_m if M is not None else None, spec_v,
                        M, V, ids, g, jnp.asarray(step, jnp.int32),
                        backend=backend, **kw)
 
 
 def test_registry_contents():
-    assert K.backends() == ("ref", "xla", "stream", "tiled", "interpret")
-    assert K.resolve_backend("tiled") == "tiled"
+    assert registry.backends("pair", "adam_rows") == (
+        "ref", "xla", "tiled", "interpret")
+    assert registry.resolve("pair", "adam_rows", "tiled") == "tiled"
     # auto resolves per host: tiled on TPU, the vectorized jnp path off it
-    assert K.resolve_backend(None) == (
+    assert registry.resolve("pair", "adam_rows", None) == (
         "tiled" if jax.default_backend() == "tpu" else "xla")
     with pytest.raises(KeyError):
-        K.resolve_backend("nope")
-
-
-def test_flat_api_is_registry_backed():
-    """The PR-1 flat API is now a view of the shared (kind, op) registry
-    (kernels/registry.py): the sparse-rows row is ('pair', 'adam_rows'),
-    and registering through the flat API lands there."""
-    from repro.kernels import registry
-    assert K.backends() == registry.backends("pair", "adam_rows")
-    sentinel = object()
-    K.register_backend("_test_probe", sentinel)
-    try:
-        assert registry.lookup("pair", "adam_rows", "_test_probe") \
-            is sentinel
-    finally:
-        registry._REGISTRY[("pair", "adam_rows")].pop("_test_probe")
-
-
-@pytest.mark.parametrize("depth", [1, 3])
-@pytest.mark.parametrize("track_m", [True, False])
-def test_stream_matches_ref_exactly(depth, track_m):
-    """Both implement the paper's per-item algorithm — exact agreement,
-    duplicates and collisions included."""
-    n, d, k = 256, 128, 12
-    spec_m, spec_v = _specs(n, d, depth, seed=depth)
-    M, V = _states(spec_m, spec_v, track_m, seed=depth)
-    rng = np.random.RandomState(depth)
-    ids = jnp.asarray(rng.randint(0, n, k), jnp.int32)   # duplicates likely
-    g = jnp.asarray(rng.randn(k, d), jnp.float32)
-    b1 = 0.9 if track_m else 0.0
-    r = _run("ref", spec_m, spec_v, M, V, ids, g, b1=b1)
-    s = _run("stream", spec_m, spec_v, M, V, ids, g, b1=b1)
-    for a, b in zip(r, s):
-        if a is None:
-            assert b is None
-            continue
-        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+        registry.resolve("pair", "adam_rows", "nope")
 
 
 @pytest.mark.parametrize("depth", [1, 3])
@@ -278,7 +236,6 @@ def test_sparse_rows_adam_routes_backends():
     'interpret' (the tiled kernel under the interpreter) and 'xla' give
     the same (table, state) trajectory."""
     from repro.core import optimizers as O
-    from repro.kernels import registry
     n, d = 512, 128
     rng = np.random.RandomState(0)
     ids = jnp.asarray(rng.permutation(n)[:16], jnp.int32)

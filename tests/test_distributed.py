@@ -1,4 +1,4 @@
-"""Sharding rules, ZeRO-1 specs, elastic planning, straggler monitor,
+"""Sharding rules, ZeRO-1 specs, elastic planning,
 sketched data-parallel reduction (traffic accounting + error feedback).
 
 Multi-replica semantics are simulated with ``vmap(axis_name=...)`` — the
@@ -12,9 +12,8 @@ import pytest
 from jax.sharding import AxisType, Mesh, PartitionSpec as P
 
 from repro.distributed import sharding as shd
-from repro.distributed.elastic import (ElasticPlan, StragglerMonitor,
-                                       elastic_restore, plan_resize,
-                                       recovery_loop)
+from repro.distributed.elastic import (ElasticPlan, elastic_restore,
+                                       plan_resize, recovery_loop)
 
 
 def _mesh(shape=(2, 1), axes=("data", "model")):
@@ -137,13 +136,6 @@ class TestElastic:
     def test_plan_resize_insufficient(self):
         with pytest.raises(ValueError):
             plan_resize(8, model_axis=16)
-
-    def test_straggler_monitor(self):
-        mon = StragglerMonitor(threshold=1.5, min_samples=3)
-        for step in range(6):
-            for host in range(4):
-                mon.record(host, 1.0 if host != 2 else 2.5)
-        assert mon.stragglers() == [2]
 
     def test_recovery_loop_restarts(self):
         state = {"restores": 0}

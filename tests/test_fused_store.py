@@ -222,14 +222,13 @@ class TestRegistry:
         assert ("countmin", "update_read") in registry.ops()
 
     def test_update_read_backends(self):
-        # batch-defined op: no 'stream' (per-item ordering is its point)
         for kind in ("sketch", "countmin"):
             assert registry.backends(kind, "update_read") == \
                 ("ref", "xla", "tiled", "interpret")
 
     def test_pair_row_keeps_pr1_contents(self):
         assert registry.backends("pair", "adam_rows") == \
-            ("ref", "xla", "stream", "tiled", "interpret")
+            ("ref", "xla", "tiled", "interpret")
 
     def test_resolve(self):
         want = "tiled" if jax.default_backend() == "tpu" else "xla"

@@ -126,12 +126,11 @@ class TestAdamParityGrid:
            compression=st.sampled_from([2.0, 5.0]),
            depth=st.sampled_from([1, 3]),
            dense_chunk=st.sampled_from([0, 128]),
-           lazy=st.sampled_from([True, False]),
            strict=st.sampled_from([True, False]),
            cleaning=st.sampled_from(sorted(CLEANINGS)),
            override=st.sampled_from([False, True]))
     def test_countsketch_adam_bit_identical(self, policy, mode, compression,
-                                            depth, dense_chunk, lazy, strict,
+                                            depth, dense_chunk, strict,
                                             cleaning, override):
         track, sketch_first, rank1 = MOMENT_MODES[mode]
         overrides = ((("tok_embed/table", (2, 32)),) if override else ())
@@ -139,7 +138,7 @@ class TestAdamParityGrid:
         def make(mod):
             hp = mod.SketchHParams(
                 compression=compression, depth=depth, width_multiple=16,
-                dense_chunk=dense_chunk, lazy=lazy, strict_paper=strict,
+                dense_chunk=dense_chunk, strict_paper=strict,
                 overrides=overrides)
             return mod.countsketch_adam(
                 1e-2, policy=POLICIES[policy], hparams=hp,
@@ -156,15 +155,14 @@ class TestMomentumAdagradParityGrid:
     @given(policy=st.sampled_from(sorted(POLICIES)),
            compression=st.sampled_from([2.0, 5.0]),
            dense_chunk=st.sampled_from([0, 128]),
-           strict=st.sampled_from([True, False]),
-           lazy=st.sampled_from([True, False]))
+           strict=st.sampled_from([True, False]))
     def test_countsketch_momentum_bit_identical(self, policy, compression,
-                                                dense_chunk, strict, lazy):
+                                                dense_chunk, strict):
         def make(mod):
             hp = mod.SketchHParams(compression=compression,
                                    width_multiple=16,
                                    dense_chunk=dense_chunk,
-                                   strict_paper=strict, lazy=lazy)
+                                   strict_paper=strict)
             return mod.countsketch_momentum(0.1, policy=POLICIES[policy],
                                             hparams=hp)
         _run_pair(make)
